@@ -4,11 +4,11 @@ coding and serve batched requests through the continuous-batching engine
 
   PYTHONPATH=src:. python examples/serve_quantized.py
 
-Multi-device quickstart (`--sharded`): the same flow over a 2-way data
-mesh faked on CPU — quantize, save the packed artifact, load it back
-*directly onto the mesh* (the v3 manifest carries per-leaf
-PartitionSpecs), and serve with the paged KV pool partitioned into one
-page-pool shard per data-axis device. Greedy outputs are checked
+Multi-device quickstart (`--sharded`, a CPU rehearsal tool): the same
+flow over a 2-way data mesh faked on CPU — quantize, save the packed
+artifact, load it back *directly onto the mesh* (the v3 manifest
+carries per-leaf PartitionSpecs), and serve with the paged KV pool
+partitioned into one page-pool shard per data-axis device. Greedy outputs are checked
 token-for-token against the single-device engine.
 
   PYTHONPATH=src:. python examples/serve_quantized.py --sharded

@@ -6,6 +6,10 @@ context it pins dim 0 of an activation to the batch (data) axes and the
 remaining dims to the given axis names, and outside any mesh (the
 single-device test/CPU path) it is an exact no-op. Model code can
 therefore call it unconditionally.
+
+Only `mesh_context` installs a mesh here; a bare `with mesh:` block is
+not seen. Meshes come from launch/mesh.py:make_mesh, whose Auto axes
+accept the sharding constraints below.
 """
 from __future__ import annotations
 
@@ -17,21 +21,9 @@ from jax.sharding import NamedSharding
 _MESH_STACK: list = []
 
 
-def _thread_mesh():
-    """Mesh installed by a plain `with mesh:` block (legacy global mesh)."""
-    try:
-        m = jax.interpreters.pxla.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:  # noqa: BLE001 — internals moved; treat as no mesh
-        pass
-    return None
-
-
 def current_mesh():
-    if _MESH_STACK:
-        return _MESH_STACK[-1]
-    return _thread_mesh()
+    """The innermost mesh installed by `mesh_context`, else None."""
+    return _MESH_STACK[-1] if _MESH_STACK else None
 
 
 @contextlib.contextmanager
@@ -39,7 +31,7 @@ def mesh_context(mesh):
     """Install `mesh` as the active mesh (stacked; reentrant)."""
     _MESH_STACK.append(mesh)
     try:
-        with mesh:
+        with jax.set_mesh(mesh):
             yield mesh
     finally:
         _MESH_STACK.pop()

@@ -229,18 +229,19 @@ def cache_pspec(cfg, path, leaf, mesh) -> P:
         # per-token latent/rope vectors are small and replicate
         return _guard(shape, P(None, data_ax, None, None, None), sizes)
 
-    # binary-coded pool leaves (quant/kv.py layout): same placement —
-    # pages ride the data axis, kv heads the model axis — applied to the
-    # codes and both scale leaves so a page's codes and scales always
-    # land on the same devices
-    if name in ("k_codes", "v_codes", "k_alphas", "v_alphas") \
-            and len(shape) == 6:
-        # (G, P, page, H, bits, hd/32) / (G, P, page, H, Gk, bits)
-        return _guard(shape, P(None, data_ax, None, model_ax, None, None),
-                      sizes)
-    if name in ("k_betas", "v_betas") and len(shape) == 5:
-        # (G, P, page, H, Gk)
-        return _guard(shape, P(None, data_ax, None, model_ax, None), sizes)
+    # binary-coded pool rows (quant/kv.py:kv_pool_rows, heads
+    # outermost): same placement — pages ride the data axis, kv heads
+    # the model axis — applied to the codes and both scale leaves so a
+    # page's codes and scales always land on the same devices. The row
+    # splits on the model axis only where whole heads do: a width that
+    # divides while the heads do not would cut a head's codes apart
+    if name in ("k_codes", "v_codes", "k_alphas", "v_alphas", "k_betas",
+                "v_betas") and len(shape) == 4:
+        # (G, P, page, H*bits*hd/32) / (G, P, page, H*Gk*bits) /
+        # (G, P, page, H*Gk)
+        head_ax = model_ax if _div(cfg.n_kv_heads, model_ax, sizes) \
+            else None
+        return _guard(shape, P(None, data_ax, None, head_ax), sizes)
 
     if name in ("k", "v") and len(shape) == 5:
         G, B, H, S, hd = shape

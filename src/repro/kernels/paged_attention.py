@@ -16,8 +16,8 @@ Unused block-table slots MUST hold a valid page id (the allocator keeps
 them 0 and reserves page 0 as a never-allocated null page); the kernel
 masks their contribution by token index, not by page id.
 
-Off-TPU the public entry runs `interpret=True` (CPU CI); `ref.py` holds
-the pure-jnp oracle.
+`kernels/ops.py:paged_decode` dispatches here on TPU and runs it in
+interpret mode off-TPU when forced; `ref.py` holds the pure-jnp oracle.
 """
 from __future__ import annotations
 
@@ -32,16 +32,12 @@ from repro.hw import WORD
 
 NEG_INF = -1e30
 
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
-
 def _fold(t, ctx, q, k, v, m_ref, l_ref, acc_ref, *, page_size, scale,
-          window, cap):
+          window, cap, kv_axes="phd"):
     """Fold one page of fp32 K/V into the flash accumulator scratch.
-    q (Hkv, rep, hd); k/v (page, Hkv, hd)."""
-    logits = jnp.einsum("hrd,phd->hrp", q, k,
+    q (Hkv, rep, hd); k/v (page, Hkv, hd), or (Hkv, page, hd) with
+    kv_axes="hpd"."""
+    logits = jnp.einsum(f"hrd,{kv_axes}->hrp", q, k,
                         preferred_element_type=jnp.float32) * scale
     if cap is not None:
         logits = cap * jnp.tanh(logits / cap)
@@ -58,7 +54,7 @@ def _fold(t, ctx, q, k, v, m_ref, l_ref, acc_ref, *, page_size, scale,
     p = jnp.exp(logits - m_new[..., None])
     l_ref[...] = l_ref[...] * r + jnp.sum(p, axis=-1)
     acc_ref[...] = acc_ref[...] * r[..., None] + jnp.einsum(
-        "hrp,phd->hrd", p, v, preferred_element_type=jnp.float32)
+        f"hrp,{kv_axes}->hrd", p, v, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
 
@@ -89,26 +85,47 @@ def _kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def _expand_page(codes, alphas, betas, hd: int):
+def _expand_page(codes, alphas, betas, *, n_kv_heads: int, hd: int):
     """VMEM dequant of one binary-coded page (the bcq_matmul expand,
-    re-oriented for the KV layout): codes (page, Hkv, bits, hd/32) u32,
-    alphas (page, Hkv, G, bits), betas (page, Hkv, G) -> fp32
-    (page, Hkv, hd). Shift-unpack the sign bitplanes, then a statically
-    unrolled per-bit multiply-add over the group-broadcast alphas."""
-    page, Hkv, bits, hdw = codes.shape
-    G = betas.shape[-1]
+    re-oriented for the KV layout). Pool rows (quant/kv.py:kv_pool_rows):
+    codes (page, Hkv*bits*hd/32) u32, alphas (page, Hkv*G*bits), betas
+    (page, Hkv*G) -> fp32 (Hkv, page, hd). Each head vector is built
+    from single-lane slices spread over the head_dim lanes with selects
+    (Mosaic lowers no 3-D gather): lane d reads word d // 32, bit
+    d % 32, and the scales of group d // gs."""
+    page = codes.shape[0]
+    G = betas.shape[-1] // n_kv_heads
+    bits = alphas.shape[-1] // betas.shape[-1]
+    hdw = hd // WORD
     gs = hd // G
-    shifts = jax.lax.broadcasted_iota(jnp.uint32,
-                                      (1, 1, 1, 1, WORD), 4)
-    planes = (codes[..., None] >> shifts) & jnp.uint32(1)
-    signs = (2.0 * planes.astype(jnp.float32) - 1.0).reshape(
-        page, Hkv, bits, G, gs)
-    acc = jnp.broadcast_to(betas[..., None].astype(jnp.float32),
-                           (page, Hkv, G, gs))
-    for i in range(bits):
-        acc = acc + alphas[..., i, None].astype(jnp.float32) * \
-            signs[:, :, i]
-    return acc.reshape(page, Hkv, hd)
+    alphas = alphas.astype(jnp.float32)
+    betas = betas.astype(jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (page, hd), 1)
+    shift = (lane % WORD).astype(jnp.uint32)
+
+    def spread(v, base, n, width):
+        """Lane d of the (page, hd) result takes v[:, base + d // width]."""
+        out = jnp.broadcast_to(v[:, base:base + 1], (page, hd))
+        for w in range(1, n):
+            out = jnp.where(lane // width == w, v[:, base + w:base + w + 1],
+                            out)
+        return out
+
+    heads = []
+    for h in range(n_kv_heads):
+        acc = spread(betas, h * G, G, gs)
+        for i in range(bits):
+            words = spread(codes, (h * bits + i) * hdw, hdw, WORD)
+            # select, not cast: Mosaic has no uint32 -> float32 conversion
+            on = ((words >> shift) & jnp.uint32(1)) == jnp.uint32(1)
+            a = jnp.broadcast_to(alphas[:, h * G * bits + i:
+                                        h * G * bits + i + 1], (page, hd))
+            for g in range(1, G):
+                c = (h * G + g) * bits + i
+                a = jnp.where(lane // gs == g, alphas[:, c:c + 1], a)
+            acc = acc + jnp.where(on, a, -a)
+        heads.append(acc)
+    return jnp.stack(heads)
 
 
 def _kernel_quant(bt_ref, cl_ref, q_ref, kc_ref, ka_ref, kb_ref, vc_ref,
@@ -128,11 +145,14 @@ def _kernel_quant(bt_ref, cl_ref, q_ref, kc_ref, ka_ref, kb_ref, vc_ref,
 
     @pl.when(t * page_size < ctx)
     def _fold_page():
-        k = _expand_page(kc_ref[0], ka_ref[0], kb_ref[0], hd)
-        v = _expand_page(vc_ref[0], va_ref[0], vb_ref[0], hd)
+        Hkv = q_ref.shape[1]
+        k = _expand_page(kc_ref[0], ka_ref[0], kb_ref[0], n_kv_heads=Hkv,
+                         hd=hd)
+        v = _expand_page(vc_ref[0], va_ref[0], vb_ref[0], n_kv_heads=Hkv,
+                         hd=hd)
         _fold(t, ctx, q_ref[0].astype(jnp.float32), k, v,
               m_ref, l_ref, acc_ref, page_size=page_size, scale=scale,
-              window=window, cap=cap)
+              window=window, cap=cap, kv_axes="hpd")
 
     @pl.when(t == pages_per_seq - 1)
     def _flush():
@@ -177,7 +197,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
                           scale=scale, window=window, cap=cap),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rep, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(block_tables, ctx_lens, q, k_pages, v_pages)
@@ -189,9 +209,9 @@ def paged_attention_quant(q, k_codes, k_alphas, k_betas, v_codes,
                           v_alphas, v_betas, block_tables, ctx_lens, *,
                           window=None, cap=None, interpret=False):
     """Fused-dequant paged decode over a binary-coded page pool
-    (quant/kv.py layout): q (B, Hkv, rep, hd); codes
-    (P, page, Hkv, bits, hd/32) u32; alphas (P, page, Hkv, G, bits);
-    betas (P, page, Hkv, G); block_tables (B, T); ctx_lens (B,).
+    (quant/kv.py pool rows): q (B, Hkv, rep, hd); codes
+    (P, page, Hkv*bits*hd/32) u32; alphas (P, page, Hkv*G*bits); betas
+    (P, page, Hkv*G); block_tables (B, T); ctx_lens (B,).
 
     Same grid/flash structure as `paged_attention`, but each grid step
     streams a page's *codes + scales* HBM->VMEM (bits/8 + scale bytes
@@ -200,8 +220,7 @@ def paged_attention_quant(q, k_codes, k_alphas, k_betas, v_codes,
     pool: decode is bandwidth-bound, so shrinking the pages shrinks the
     time. Returns (B, Hkv, rep, hd) in q.dtype."""
     B, Hkv, rep, hd = q.shape
-    _, page_size, _, bits, hdw = k_codes.shape
-    G = k_betas.shape[-1]
+    page_size = k_codes.shape[1]
     T = block_tables.shape[1]
     scale = hd ** -0.5
 
@@ -216,12 +235,8 @@ def paged_attention_quant(q, k_codes, k_alphas, k_betas, v_codes,
         in_specs=[
             pl.BlockSpec((1, Hkv, rep, hd),
                          lambda b, t, bt, cl: (b, 0, 0, 0)),
-            page_spec((page_size, Hkv, bits, hdw)),   # k codes
-            page_spec((page_size, Hkv, G, bits)),     # k alphas
-            page_spec((page_size, Hkv, G)),           # k betas
-            page_spec((page_size, Hkv, bits, hdw)),   # v codes
-            page_spec((page_size, Hkv, G, bits)),     # v alphas
-            page_spec((page_size, Hkv, G)),           # v betas
+            *(page_spec(a.shape[1:]) for a in (k_codes, k_alphas, k_betas,
+                                               v_codes, v_alphas, v_betas)),
         ],
         out_specs=pl.BlockSpec((1, Hkv, rep, hd),
                                lambda b, t, bt, cl: (b, 0, 0, 0)),
@@ -237,7 +252,7 @@ def paged_attention_quant(q, k_codes, k_alphas, k_betas, v_codes,
                           cap=cap, hd=hd),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rep, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(block_tables, ctx_lens, q, k_codes, k_alphas, k_betas,

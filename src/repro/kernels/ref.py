@@ -104,18 +104,21 @@ def paged_attention_quant_ref(q, k_codes, k_alphas, k_betas, v_codes,
     """Oracle for the fused-dequant kernel, and the non-TPU execution
     path for quantized paged decode: gather each sequence's binary-coded
     pages through the block table, expand codes -> fp32 K/V
-    (quant/kv.py layout: codes (P, page, Hkv, bits, hd/32) u32, alphas
-    (P, page, Hkv, G, bits), betas (P, page, Hkv, G)), then the same
+    (quant/kv.py pool rows: codes (P, page, Hkv*bits*hd/32) u32, alphas
+    (P, page, Hkv*G*bits), betas (P, page, Hkv*G)), then the same
     masked softmax as paged_attention_ref."""
-    from repro.quant.kv import kv_dequantize
+    from repro.quant.kv import kv_dequantize, kv_pool_views
 
     B, Hkv, rep, hd = q.shape
     page = k_codes.shape[1]
     T = block_tables.shape[1]
-    k = kv_dequantize(k_codes[block_tables], k_alphas[block_tables],
-                      k_betas[block_tables])       # (B, T, page, Hkv, hd)
-    v = kv_dequantize(v_codes[block_tables], v_alphas[block_tables],
-                      v_betas[block_tables])
+
+    def pages(codes, alphas, betas):          # -> (B, T, page, Hkv, hd)
+        return kv_dequantize(*kv_pool_views(
+            codes[block_tables], alphas[block_tables], betas[block_tables],
+            Hkv))
+    k = pages(k_codes, k_alphas, k_betas)
+    v = pages(v_codes, v_alphas, v_betas)
     k = k.reshape(B, T * page, Hkv, hd).transpose(0, 2, 1, 3)
     v = v.reshape(B, T * page, Hkv, hd).transpose(0, 2, 1, 3)
     return _paged_attend(q, k, v, ctx_lens, window=window, cap=cap)

@@ -13,9 +13,12 @@ so a relaunch boots without re-running calibration or the GPTQ solves:
 
   # sharded serving: the packed artifact loads straight onto a 2-way
   # data mesh (per-leaf PartitionSpecs from the v3 manifest) and the
-  # paged page pool is partitioned over the same axis
+  # paged page pool is partitioned over the same axis. `--devices 2`
+  # fakes two CPU devices for a rehearsal; on a TPU host leave it out
   PYTHONPATH=src python -m repro.launch.serve --devices 2 --mesh 2,1 \\
       --load-quantized artifacts/packed/tiny-w3 --requests 6
+
+A process holds the chips it touches, so run one launcher per host.
 """
 from __future__ import annotations
 
@@ -29,10 +32,11 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tiny-lm")
     ap.add_argument("--devices", type=int, default=0,
-                    help="force N host (CPU) devices via XLA_FLAGS — a "
-                         "laptop-scale stand-in for a real multi-chip "
-                         "mesh (must be set before jax initializes, so "
-                         "it is a launcher flag)")
+                    help="CPU rehearsal only: force N host devices via "
+                         "XLA_FLAGS as a stand-in for a multi-chip mesh "
+                         "(must be set before jax initializes, so it is "
+                         "a launcher flag); on a TPU host the chips are "
+                         "the devices")
     ap.add_argument("--mesh", default=None, metavar="D,M",
                     help="serve over a (data, model) mesh, e.g. 2,1: "
                          "the paged KV pool shards its pages over the "
@@ -102,9 +106,14 @@ def main():
             + f" --xla_force_host_platform_device_count={args.devices}"
         ).strip()
 
+    import jax
+
     from repro.configs import get_config
     from repro.data import ByteTokenizer
     from repro.serve import Request, ServeEngine
+    from repro.serve.compile_cache import enable_persistent_cache
+
+    enable_persistent_cache()
 
     mesh = None
     if args.mesh:
@@ -224,8 +233,7 @@ def main():
         if paged:
             ap.error("--cache dense conflicts with --mesh/--kv-bits/"
                      "--speculate (each requires the paged backend)")
-    eng = ServeEngine(cfg, params, batch_size=batch,
-                      max_len=160, dtype="float32",
+    eng = ServeEngine(cfg, params, batch_size=batch, max_len=160,
                       cache_kind="paged" if paged else "dense",
                       mesh=mesh, kv_bits=args.kv_bits,
                       kv_group_size=args.kv_group_size,
@@ -245,7 +253,7 @@ def main():
         kv = eng.kv
         raw = kv.__class__(cfg, n_pages=kv.n_pages,
                            page_size=kv.page_size, max_seqs=kv.max_seqs,
-                           dtype="float32",
+                           dtype=cfg.dtype,
                            create_pool=False).bytes_per_page()
         print(f"quantized KV cache: {args.kv_bits}-bit binary-coded "
               f"pages, {kv.bytes_per_page()} B/page vs {raw} B/page raw "
@@ -263,8 +271,10 @@ def main():
             for i in range(args.requests)]
     eng.run(reqs)
     tput = eng.stats["tokens"] / max(eng.stats["decode_s"], 1e-9)
+    dev = jax.devices()[0]
     print(f"served {len(reqs)} requests, {eng.stats['tokens']} tokens, "
-          f"decode throughput {tput:.1f} tok/s (CPU)")
+          f"decode throughput {tput:.1f} tok/s on {dev.platform} "
+          f"({dev.device_kind})")
     for r in reqs[:3]:
         print(" ", repr(tok.decode(r.out)))
 

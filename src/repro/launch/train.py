@@ -18,6 +18,7 @@ from repro.ckpt import CheckpointManager
 from repro.data import batches, token_stream
 from repro.dist.sharding import (inputs_shardings, opt_state_shardings,
                                  params_shardings)
+from repro.dist.context import mesh_context
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.model import init_params
 from repro.train.optimizer import AdamWConfig
@@ -50,7 +51,7 @@ def main():
     toks = token_stream("wiki", 400_000)
     data = batches(toks, args.batch, args.seq, seed=0)
 
-    with mesh:
+    with mesh_context(mesh):
         key = jax.random.PRNGKey(0)
         params = init_params(cfg, key)
         opt_state = init_train_state(cfg, params, opt_cfg)

@@ -188,14 +188,15 @@ def init_paged_kv(cfg, n_pages, page_size, dtype, kv_bits=0,
     from repro.quant.kv import kv_layout
     G, hdw = kv_layout(hd, kv_bits, kv_group_size)
     Hkv = cfg.n_kv_heads
-    lead = (n_pages, page_size, Hkv)
+    lead = (n_pages, page_size)
     pool = {}
     for side in ("k", "v"):
-        pool[f"{side}_codes"] = jnp.zeros(lead + (kv_bits, hdw),
+        # one lane-dense row per token (quant/kv.py:kv_pool_rows)
+        pool[f"{side}_codes"] = jnp.zeros(lead + (Hkv * kv_bits * hdw,),
                                           jnp.uint32)
-        pool[f"{side}_alphas"] = jnp.zeros(lead + (G, kv_bits),
+        pool[f"{side}_alphas"] = jnp.zeros(lead + (Hkv * G * kv_bits,),
                                            jnp.float32)
-        pool[f"{side}_betas"] = jnp.zeros(lead + (G,), jnp.float32)
+        pool[f"{side}_betas"] = jnp.zeros(lead + (Hkv * G,), jnp.float32)
     return pool
 
 
@@ -220,22 +221,13 @@ def paged_kv_page_bytes(cfg, page_size, dtype, kv_bits=0,
     return 2 * page_size * cfg.n_kv_heads * per_vec * n_attn
 
 
-# None = auto (Pallas kernel iff backend is TPU; the pure-jnp gather
-# otherwise). Tests may force the kernel in interpret mode.
-FORCE_PAGED_KERNEL: bool | None = None
-
-
-def _use_paged_kernel() -> bool:
-    if FORCE_PAGED_KERNEL is not None:
-        return FORCE_PAGED_KERNEL
-    return jax.default_backend() == "tpu"
-
-
 def paged_kv_bits(cache) -> int:
     """kv_bits of a paged layer cache (0 = unquantized). The layout is
     self-describing: bits/groups are leaf shapes, so jit wrappers need
     no extra static arguments to dispatch."""
-    return cache["k_codes"].shape[-2] if "k_codes" in cache else 0
+    if "k_codes" not in cache:
+        return 0
+    return cache["k_alphas"].shape[-1] // cache["k_betas"].shape[-1]
 
 
 def _quant_scatter(cache, side, new, pid, off, mask=None):
@@ -243,11 +235,11 @@ def _quant_scatter(cache, side, new, pid, off, mask=None):
     scatter codes+scales into the pool at (pid, off). With `mask`
     (matching new's leading dims), False rows re-write the null page's
     slot-0 content instead (the extend path's padding trick)."""
-    from repro.quant.kv import kv_quantize
-    bits = cache[f"{side}_codes"].shape[-2]
-    G = cache[f"{side}_betas"].shape[-1]
+    from repro.quant.kv import kv_pool_rows, kv_quantize
+    bits = paged_kv_bits(cache)
+    G = cache[f"{side}_betas"].shape[-1] // new.shape[-2]
     gs = new.shape[-1] // G
-    codes, alphas, betas = kv_quantize(new, bits, gs)
+    codes, alphas, betas = kv_pool_rows(*kv_quantize(new, bits, gs))
     out = dict(cache)
     for name, val in ((f"{side}_codes", codes),
                       (f"{side}_alphas", alphas),
@@ -262,17 +254,16 @@ def _quant_scatter(cache, side, new, pid, off, mask=None):
     return out
 
 
-def _gather_dequant(cache, side, block_tables, hd):
+def _gather_dequant(cache, side, block_tables, Hkv, hd):
     """Gather + expand a sequence's binary-coded pages:
     -> (B, T*page, Hkv, hd) fp32 (the extend path's dense view)."""
-    from repro.quant.kv import kv_dequantize
+    from repro.quant.kv import kv_dequantize, kv_pool_views
     bt = block_tables
     B, T = bt.shape
     page = cache[f"{side}_codes"].shape[1]
-    Hkv = cache[f"{side}_codes"].shape[2]
-    x = kv_dequantize(cache[f"{side}_codes"][bt],
-                      cache[f"{side}_alphas"][bt],
-                      cache[f"{side}_betas"][bt])
+    x = kv_dequantize(*kv_pool_views(cache[f"{side}_codes"][bt],
+                                     cache[f"{side}_alphas"][bt],
+                                     cache[f"{side}_betas"][bt], Hkv))
     return x.reshape(B, T * page, Hkv, hd)
 
 
@@ -311,38 +302,12 @@ def attn_decode_paged(cfg, spec, p, x, cache, block_tables, pos):
 
     qg = q[:, 0].reshape(B, cfg.n_kv_heads,
                          cfg.n_heads // cfg.n_kv_heads, hd)
-    ctx = pos + 1
-    interpret = jax.default_backend() != "tpu"
-    if quant:
-        if _use_paged_kernel():
-            from repro.kernels.paged_attention import paged_attention_quant
-            out = paged_attention_quant(
-                qg, cache["k_codes"], cache["k_alphas"], cache["k_betas"],
-                cache["v_codes"], cache["v_alphas"], cache["v_betas"],
-                block_tables, ctx, window=spec.window,
-                cap=cfg.attn_softcap, interpret=interpret)
-        else:
-            from repro.kernels.ref import paged_attention_quant_ref
-            out = paged_attention_quant_ref(
-                qg, cache["k_codes"], cache["k_alphas"], cache["k_betas"],
-                cache["v_codes"], cache["v_alphas"], cache["v_betas"],
-                block_tables, ctx, window=spec.window,
-                cap=cfg.attn_softcap)
-    elif _use_paged_kernel():
-        from repro.kernels.paged_attention import paged_attention
-        out = paged_attention(qg, cache["k_pages"], cache["v_pages"],
-                              block_tables, ctx,
-                              window=spec.window, cap=cfg.attn_softcap,
-                              interpret=interpret)
-    else:
-        # gather path: the kernel's oracle doubles as the non-TPU
-        # execution path (same fp32 masked softmax the dense attn_decode
-        # computes, so paged and dense engines agree token-for-token on
-        # the fp32 CPU tests)
-        from repro.kernels.ref import paged_attention_ref
-        out = paged_attention_ref(qg, cache["k_pages"], cache["v_pages"],
-                                  block_tables, ctx,
-                                  window=spec.window, cap=cfg.attn_softcap)
+    # kernel on TPU, the jnp gather oracle elsewhere (the same fp32
+    # masked softmax the dense attn_decode computes, so paged and dense
+    # engines agree token-for-token on the fp32 CPU tests)
+    from repro.kernels import ops
+    out = ops.paged_decode(qg, cache, block_tables, pos + 1,
+                           window=spec.window, cap=cfg.attn_softcap)
     out = out.reshape(B, 1, cfg.n_heads * hd)
     y = linear(out, p["wo"])
     return y, cache
@@ -374,8 +339,8 @@ def attn_extend_paged(cfg, spec, p, h, cache, block_tables, start_pos,
     if quant:
         cache = _quant_scatter(cache, "k", k, pid, off, mask=chunk_mask)
         cache = _quant_scatter(cache, "v", v, pid, off, mask=chunk_mask)
-        ck = _gather_dequant(cache, "k", block_tables, hd)
-        cv = _gather_dequant(cache, "v", block_tables, hd)
+        ck = _gather_dequant(cache, "k", block_tables, cfg.n_kv_heads, hd)
+        cv = _gather_dequant(cache, "v", block_tables, cfg.n_kv_heads, hd)
     else:
         kp, vp = cache["k_pages"], cache["v_pages"]
         m4 = chunk_mask[:, :, None, None]

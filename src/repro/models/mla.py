@@ -102,7 +102,7 @@ def init_mla_paged(cfg, n_pages, page_size, dtype):
     page_size * (kv_lora_rank + qk_rope_head_dim) elements instead of
     2 * page_size * Hkv * hd. The singleton dim-2 axis keeps the leaves
     shaped like attention pools ((pages, page, heads, vec)) so
-    is_page_leaf / copy_pages / compact treat them identically."""
+    map_page_leaves / copy_pages / compact treat them identically."""
     m = cfg.mla
     return {"ckv_pages": jnp.zeros((n_pages, page_size, 1,
                                     m.kv_lora_rank), dtype),

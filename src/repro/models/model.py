@@ -273,12 +273,21 @@ def init_paged_cache(cfg, n_pages, page_size, max_seqs, dtype=None,
     return cache
 
 
-def is_page_leaf(leaf, n_pages) -> bool:
-    """A paged-pool leaf: page axis at dim 1 after the group stack. Both
-    the raw layout (ndim 5) and the quantized code/alpha/beta leaves
-    (ndim 5-6) match; mamba per-slot state (G, max_seqs, ...) does not
-    (its dim 1 is max_seqs, never n_pages in practice)."""
-    return leaf.ndim >= 5 and leaf.shape[1] == n_pages
+# leaf names of the page pools (raw K/V, binary-coded K/V rows, MLA
+# latents); every one has its page axis at dim 1 after the group stack.
+# Per-slot recurrent (mamba) state is not among them.
+PAGE_LEAVES = frozenset(
+    f"{side}_{kind}" for side in ("k", "v")
+    for kind in ("pages", "codes", "alphas", "betas")) | {"ckv_pages",
+                                                         "kpe_pages"}
+
+
+def map_page_leaves(fn, cache):
+    """Apply fn to every page-pool leaf of a paged cache, leaving
+    per-slot state alone."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, leaf: fn(leaf) if path[-1].key in PAGE_LEAVES
+        else leaf, cache)
 
 
 def copy_pages(cache, src, dst, n_pages):
@@ -290,11 +299,8 @@ def copy_pages(cache, src, dst, n_pages):
     magnitudes under the new page's signs. src/dst are (n,) int32 page
     ids; (0, 0) pairs are harmless null-page no-ops, used by the engine
     to pad the copy list to a fixed trace shape."""
-    def move(leaf):
-        if is_page_leaf(leaf, n_pages):
-            return leaf.at[:, dst].set(leaf[:, src])
-        return leaf
-    return jax.tree.map(move, cache)
+    return map_page_leaves(lambda leaf: leaf.at[:, dst].set(leaf[:, src]),
+                           cache)
 
 
 def _last_positions(x, last_pos):
@@ -546,20 +552,20 @@ def scatter_prefill_cache(cfg, paged_cache, row_cache, slot, page_ids,
             return r.reshape(G, npg, page, Hkv, hd)
 
         if quant:
-            from repro.quant.kv import kv_quantize
+            from repro.quant.kv import kv_pool_rows, kv_quantize
 
-            bits = pooled["k_codes"].shape[-2]
-            Gk = pooled["k_betas"].shape[-1]
+            bits = attn.paged_kv_bits(pooled)
+            Gk = pooled["k_betas"].shape[-1] // cfg.n_kv_heads
 
             def put_q(side, one):
                 hd = one.shape[-1]
                 r = paged_rows(one)
-                vals = kv_quantize(r, bits, hd // Gk)
+                vals = kv_pool_rows(*kv_quantize(r, bits, hd // Gk))
                 keep = (jnp.arange(npg * page) < n_valid).reshape(npg, page)
                 leaves = {}
                 for suffix, val in zip(("codes", "alphas", "betas"), vals):
                     pool = pooled[f"{side}_{suffix}"]
-                    km = keep.reshape((1, npg, page) + (1,) * (val.ndim - 3))
+                    km = keep.reshape(1, npg, page, 1)
                     cur = pool[:, page_ids]
                     leaves[f"{side}_{suffix}"] = pool.at[:, page_ids].set(
                         jnp.where(km, val.astype(pool.dtype), cur))

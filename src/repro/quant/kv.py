@@ -23,6 +23,11 @@ Layout per (token, head), head_dim = hd, G = hd / group_size:
     alphas (..., G, bits)      float32  per-group magnitudes
     betas  (..., G)            float32  per-group offsets
 
+Page pools store these flattened to one row per token, heads
+outermost (`kv_pool_rows`: codes (..., Hkv*bits*hd/32), alphas
+(..., Hkv*G*bits), betas (..., Hkv*G)), so the TPU tiles pages
+lane-dense; `kv_pool_views` restores the per-head shapes.
+
 Bytes per (token, head): 4*bits*hd/32 + 4*G*bits + 4*G, vs 4*hd for an
 fp32 page and 2*hd for bf16 — at hd=64, bits=4, G=1: 52 B vs 256/128 B
 (4.9x / 2.5x). `kv_bytes_per_token_head` is the single owner of that
@@ -122,6 +127,27 @@ def kv_dequantize(codes, alphas, betas, dtype=jnp.float32):
     w = jnp.einsum("...bgk,...gb->...gk", sg,
                    alphas.astype(jnp.float32)) + betas[..., None]
     return w.reshape(*lead, hd).astype(dtype)
+
+
+def kv_pool_rows(codes, alphas, betas):
+    """Flatten coded vectors to the page-pool layout: one lane-dense row
+    per token, heads outermost. codes (..., Hkv, bits, hd/32) ->
+    (..., Hkv*bits*hd/32); alphas (..., Hkv, G, bits) -> (..., Hkv*G*bits);
+    betas (..., Hkv, G) -> (..., Hkv*G). A (bits, hd/32) minor tile
+    would be padded to a whole (8, 128) TPU tile in HBM and VMEM."""
+    return (codes.reshape(*codes.shape[:-3], -1),
+            alphas.reshape(*alphas.shape[:-3], -1),
+            betas.reshape(*betas.shape[:-2], -1))
+
+
+def kv_pool_views(codes, alphas, betas, n_kv_heads: int):
+    """Inverse of kv_pool_rows: per-head (codes, alphas, betas) views of
+    page-pool rows. bits and G follow from the row widths."""
+    G = betas.shape[-1] // n_kv_heads
+    bits = alphas.shape[-1] // betas.shape[-1]
+    return (codes.reshape(*codes.shape[:-1], n_kv_heads, bits, -1),
+            alphas.reshape(*alphas.shape[:-1], n_kv_heads, G, bits),
+            betas.reshape(*betas.shape[:-1], n_kv_heads, G))
 
 
 def kv_bytes_per_token_head(head_dim: int, kv_bits: int,

@@ -22,8 +22,14 @@ Keying rules:
 `stats()` exposes hit/miss counters; tests assert that constructing a
 second engine adds zero entries and that its runs add zero XLA
 compilations (`jitted._cache_size()` is flat).
+
+`enable_persistent_cache()` is the on-disk side: XLA executables
+survive the process, so a relaunch skips recompiling the same steps.
 """
 from __future__ import annotations
+
+import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +41,20 @@ from repro.models.model import (copy_pages, decode_step, decode_step_paged,
 
 _CACHE: dict = {}
 _STATS = {"hits": 0, "misses": 0}
+# fixed, git-ignored home of the persistent cache inside the checkout:
+# the directory is part of each entry's key, so it never moves
+PERSISTENT_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache before the first
+    compile; returns its directory. Where JAX_COMPILATION_CACHE_DIR is
+    set, JAX already keeps the cache there and nothing else is set."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(PERSISTENT_DIR))
+    return str(PERSISTENT_DIR)
 
 
 def mesh_fingerprint(mesh):

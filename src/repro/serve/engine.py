@@ -254,13 +254,13 @@ class ServeEngine:
             # COW, release) instead of re-uploading the whole table per
             # decode tick; the per-tick traffic is just the (B,) live
             # mask that routes inactive rows to their shard's null page
-            self._bt_dev = jnp.zeros((batch_size, pages_per_seq), jnp.int32)
+            self._bt_dev = jnp.asarray(self.kv.block_tables)
             self._bt_applied = np.full((batch_size,), -1, np.int64)
             # per-slot null-page row: all zeros unsharded; shard s's
             # reserve page for slots living on shard s
             self._null_row = jnp.asarray(
-                [self.kv.null_page_of_shard(self.kv.shard_of_slot(s))
-                 for s in range(batch_size)], jnp.int32)
+                [self.kv.null_page_of_slot(s) for s in range(batch_size)],
+                jnp.int32)
             self._bt_update = compile_cache.get("bt_update", None, mesh)
             self._decode = compile_cache.get("decode_paged", cfg, mesh)
             self._scatter = compile_cache.get("scatter_prefill", cfg,
@@ -455,7 +455,8 @@ class ServeEngine:
                                                    last, len(padded))
             npg = -(-len(padded) // self.page_size)
             ids = self.kv.owned_pages(e.slot)
-            ids = (ids + [0] * npg)[:npg]       # null-page pad: masked out
+            # reserve-page pad (masked out), inside the slot's shard
+            ids = (ids + [self.kv.null_page_of_slot(e.slot)] * npg)[:npg]
             self.cache = self._scatter(self.cache, row_cache,
                                        jnp.int32(e.slot),
                                        jnp.asarray(ids, jnp.int32),

@@ -7,8 +7,8 @@ Layout:
     batch rows and masked prefill padding write there so the scatter in
     the decode step needs no branch.
   - block table: (max_seqs, max_pages_per_seq) int32, row = sequence
-    slot, entry = page id (0 for unused slots, which is always a valid
-    DMA target for the Pallas kernel).
+    slot, entry = page id (the slot's reserve page for unused entries,
+    see below; always a valid DMA target for the Pallas kernel).
 
 Sharding (`n_shards > 1`): the pool's page axis is partitioned into
 `n_shards` equal contiguous blocks matching the GSPMD layout of the
@@ -22,9 +22,13 @@ decode gather and the prefill scatter stay device-local. The first
 page of each shard's block (`null_page_of_shard`) is a per-shard
 *reserve* page, never allocated: masked rows of that shard write there
 (the engine routes inactive rows via a per-slot null-page row instead
-of the constant 0). All allocator invariants below hold *per shard*;
-with `n_shards == 1` the layout degenerates to the original global
-pool (reserve page == null page 0).
+of the constant 0), and every block-table entry past a sequence's
+pages holds it. The paged decode kernel reads each shard's block with
+page ids rebased onto that block and fetches a page for every
+block-table entry, masked or not, so an entry outside the shard's
+block would be an out-of-bounds read. All allocator invariants below
+hold *per shard*; with `n_shards == 1` the layout degenerates to the
+original global pool (reserve page == null page 0).
 
 Pages are *refcounted* so completed prefill pages can be shared between
 sequences through the radix prefix index (serve/prefix_cache.py): a page
@@ -52,13 +56,15 @@ tests/test_alloc_property.py):
   - reserve pages (the null page 0 and each shard's first page) are
     never allocated, shared, or forked;
   - every page owned by slot s belongs to shard_of_slot(s)'s block;
-  - block-table entries beyond a sequence's page count are 0.
+  - block-table entries beyond a sequence's page count are the slot's
+    reserve page (`null_page_of_slot`; 0 unsharded), so every entry of
+    a row lies in its shard's block.
 """
 from __future__ import annotations
 
 import numpy as np
 
-from repro.models.model import init_paged_cache, is_page_leaf
+from repro.models.model import init_paged_cache, map_page_leaves
 
 
 class OutOfPages(Exception):
@@ -109,8 +115,9 @@ class PagedKVCache:
                      if create_pool else None)
         self._created_pool = bool(create_pool)
         self._pool_taken = False
-        self.block_tables = np.zeros((max_seqs, self.max_pages_per_seq),
-                                     np.int32)
+        self.block_tables = np.repeat(
+            np.asarray([self.null_page_of_slot(s) for s in range(max_seqs)],
+                       np.int32)[:, None], self.max_pages_per_seq, axis=1)
         # monotone per-row versions: bumped on every block-table mutation
         # so the engine can mirror rows to a device-resident copy
         # incrementally instead of re-uploading the whole table per tick
@@ -141,6 +148,11 @@ class PagedKVCache:
         """The shard's reserve page: masked/inactive rows of that shard
         write there (page 0 for shard 0 and for unsharded pools)."""
         return shard * self.pages_per_shard
+
+    def null_page_of_slot(self, slot: int) -> int:
+        """The reserve page of the slot's shard: what the slot's unused
+        block-table entries hold."""
+        return self.null_page_of_shard(self.shard_of_slot(slot))
 
     def is_reserve_page(self, pid: int) -> bool:
         """True for every shard's reserve page — page 0 and each
@@ -368,7 +380,7 @@ class PagedKVCache:
         for pid in self._owned[slot]:
             self.unref(pid)
         self._owned[slot] = []
-        self.block_tables[slot, :] = 0
+        self.block_tables[slot, :] = self.null_page_of_slot(slot)
         self.bt_version[slot] += 1
         self._active[slot] = False
 
@@ -385,7 +397,8 @@ class PagedKVCache:
         owned = self._owned[slot]
         assert keep <= len(owned), (slot, n_tokens, len(owned))
         dropped = owned[keep:]
-        self.block_tables[slot, keep:keep + len(dropped)] = 0
+        self.block_tables[slot, keep:keep + len(dropped)] = \
+            self.null_page_of_slot(slot)
         del owned[keep:]
         for pid in dropped:
             self.unref(pid)
@@ -448,15 +461,11 @@ class PagedKVCache:
         self._refcount = new_rc
 
         if pool is not None:
-            def move(leaf):
-                # page pools have the page axis at dim 1 (after the group
-                # stack); per-slot state (mamba) is left alone. On a
-                # binary-coded pool this moves codes AND scale leaves.
-                if is_page_leaf(leaf, self.n_pages):
-                    return leaf[:, jnp.asarray(src)]
-                return leaf
-
-            pool = jax.tree.map(move, pool)
+            # page pools have the page axis at dim 1 (after the group
+            # stack); per-slot state (mamba) is left alone. On a
+            # binary-coded pool this moves codes AND scale leaves.
+            pool = map_page_leaves(lambda leaf: leaf[:, jnp.asarray(src)],
+                                   pool)
         self._free_by_shard = [
             list(range((s + 1) * self.pages_per_shard - 1,
                        next_in_shard[s] - 1, -1))

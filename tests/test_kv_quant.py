@@ -11,11 +11,18 @@ from repro.configs import get_config
 from repro.kernels import ref
 from repro.kernels.paged_attention import paged_attention_quant
 from repro.models.attention import paged_kv_page_bytes
-from repro.models.model import copy_pages, init_paged_cache, is_page_leaf
+from repro.models.model import (PAGE_LEAVES, copy_pages, init_paged_cache,
+                                map_page_leaves)
 from repro.quant.kv import (kv_bytes_per_token_head, kv_dequantize,
-                            kv_layout, kv_quantize)
+                            kv_layout, kv_pool_rows, kv_pool_views,
+                            kv_quantize)
 
 KEY = jax.random.PRNGKey(0)
+
+
+def page_leaves(cache):
+    return [leaf for path, leaf in jax.tree_util.tree_leaves_with_path(cache)
+            if path[-1].key in PAGE_LEAVES]
 
 
 def _tiny_cfg():
@@ -64,6 +71,17 @@ def test_kv_quantize_shapes_and_dtypes():
     assert betas.shape == (3, 5, 2) and betas.dtype == jnp.float32
 
 
+def test_kv_pool_rows_roundtrip():
+    """Page pools store one lane-dense row per token, heads outermost;
+    the per-head views come back bit-identical."""
+    x = jax.random.normal(KEY, (3, 5, 4, 64), jnp.float32)   # Hkv = 4
+    coded = kv_quantize(x, 3, kv_group_size=32)
+    rows = kv_pool_rows(*coded)
+    assert [r.shape for r in rows] == [(3, 5, 24), (3, 5, 24), (3, 5, 8)]
+    for a, b in zip(kv_pool_views(*rows, n_kv_heads=4), coded):
+        assert a.shape == b.shape and bool((a == b).all())
+
+
 def test_kv_layout_validation():
     assert kv_layout(64, 4) == (1, 2)
     assert kv_layout(64, 2, 16) == (4, 2)
@@ -85,7 +103,7 @@ def test_kv_bytes_per_token_head():
     for bits in (0, 4):
         cache = init_paged_cache(cfg, n_pages=6, page_size=8, max_seqs=2,
                                  kv_bits=bits)
-        leaves = [l for l in jax.tree.leaves(cache) if is_page_leaf(l, 6)]
+        leaves = page_leaves(cache)
         assert sum(l.nbytes for l in leaves) // 6 \
             == paged_kv_page_bytes(cfg, 8, "float32", kv_bits=bits)
 
@@ -99,7 +117,8 @@ def _quant_pool(rng, P, page, Hkv, hd, bits):
     v = jnp.asarray(rng.standard_normal((P, page, Hkv, hd)), jnp.float32)
     # iters=1 keeps the sweep fast; kernel parity is about consuming the
     # codes, not about how well they were fitted
-    return kv_quantize(k, bits, iters=1) + kv_quantize(v, bits, iters=1)
+    return (kv_pool_rows(*kv_quantize(k, bits, iters=1))
+            + kv_pool_rows(*kv_quantize(v, bits, iters=1)))
 
 
 @pytest.mark.parametrize("page,bits", [(8, 1), (8, 4), (16, 2), (16, 4),
@@ -151,7 +170,8 @@ def test_quant_oracle_approaches_fp_oracle_with_bits():
     want = ref.paged_attention_ref(q, kp, vp, bt, ctx)
     errs = []
     for bits in (2, 4, 8):
-        pool = kv_quantize(kp, bits) + kv_quantize(vp, bits)
+        pool = (kv_pool_rows(*kv_quantize(kp, bits))
+                + kv_pool_rows(*kv_quantize(vp, bits)))
         got = ref.paged_attention_quant_ref(q, *pool, bt, ctx)
         errs.append(float(jnp.abs(got - want).max()))
     # random N(0,1) K/V is the adversarial case (softmax amplifies any
@@ -184,11 +204,10 @@ def test_copy_pages_moves_codes_and_scales():
             val = jax.random.normal(k, leaf[:, 2].shape, dtype=leaf.dtype)
         return leaf.at[:, 2].set(val)
 
-    cache = jax.tree.map(
-        lambda l: fill(l) if is_page_leaf(l, n_pages) else l, cache)
+    cache = map_page_leaves(fill, cache)
     out = copy_pages(cache, jnp.asarray([2], jnp.int32),
                      jnp.asarray([4], jnp.int32), n_pages)
-    leaves = [l for l in jax.tree.leaves(out) if is_page_leaf(l, n_pages)]
+    leaves = page_leaves(out)
     # k/v x codes/alphas/betas (layers stack along the scan-group axis)
     assert len(leaves) == 6
     for leaf in leaves:

@@ -10,6 +10,7 @@ import pytest
 from repro.ckpt.packed import load_packed, save_packed
 from repro.configs import get_config
 from repro.core import quantize_model
+from repro.launch.mesh import make_host_mesh
 from repro.models import init_params
 from repro.quant import OverrideRule, QuantSpec, QuantizedTensor
 from repro.serve import PagedKVCache, RadixPrefixCache, Request, ServeEngine
@@ -236,7 +237,7 @@ def test_v2_artifact_loads_and_warns_on_mesh(tmp_path):
         if isinstance(lq, QuantizedTensor):
             np.testing.assert_array_equal(np.asarray(lq.codes),
                                           np.asarray(ll.codes))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     packed_mod._WARNED_NO_PSPEC = False
     with pytest.warns(UserWarning, match="REPLICATED"):
         load_packed(d, mesh=mesh)

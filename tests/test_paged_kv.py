@@ -212,17 +212,17 @@ def test_chunked_prefill_matches_dense_greedy():
 def test_paged_engine_through_interpret_kernel():
     """Force the Pallas kernel (interpret mode off-TPU) for engine decode
     — the full wiring model -> kernel, not just the oracle comparison."""
-    from repro.models import attention as attn_mod
+    from repro.kernels import ops
     cfg = _tiny_cfg()
     p = init_params(cfg, KEY)
     want, _ = _run(cfg, p, _reqs(cfg, 2, max_new=4), batch_size=2,
                    max_len=48)
-    attn_mod.FORCE_PAGED_KERNEL = True
+    ops.FORCE_PALLAS = True
     try:
         got, _ = _run(cfg, p, _reqs(cfg, 2, max_new=4), batch_size=2,
                       max_len=48, cache_kind="paged", page_size=16)
     finally:
-        attn_mod.FORCE_PAGED_KERNEL = None
+        ops.FORCE_PALLAS = None
     assert got == want
 
 

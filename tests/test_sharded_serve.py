@@ -9,6 +9,7 @@ import pytest
 import jax
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models import init_params
 from repro.serve import (OutOfPages, PagedKVCache, RadixPrefixCache,
                          Request, ServeEngine)
@@ -28,7 +29,7 @@ def _tiny_cfg():
 
 
 def _mesh2():
-    return jax.make_mesh((2, 1), ("data", "model"))
+    return make_mesh((2, 1), ("data", "model"))
 
 
 def _kv(n_pages=16, page_size=4, max_seqs=4, n_shards=2, **kw):
@@ -52,6 +53,20 @@ def _check_shard_invariants(kv):
             assert kv.shard_of_page(pid) == kv.shard_of_slot(s)
             assert pid not in [kv.null_page_of_shard(x)
                                for x in range(kv.n_shards)]
+        _check_row_in_block(kv, s)
+
+
+def _check_row_in_block(kv, s):
+    """Every block-table entry of slot s, unused ones included, lies in
+    its shard's block: rebased onto that block as the sharded paged
+    decode rebases it (kernels/ops.py:paged_decode), each id is a valid
+    local page in [0, pages_per_shard)."""
+    base = kv.null_page_of_shard(kv.shard_of_slot(s))
+    local = kv.block_tables[s] - base
+    assert ((local >= 0) & (local < kv.pages_per_shard)).all(), \
+        (s, kv.block_tables[s], base)
+    n = len(kv.owned_pages(s))
+    assert (kv.block_tables[s, n:] == base).all()
 
 
 # ---------------------------------------------------------------------------
@@ -135,6 +150,30 @@ def test_compact_remaps_within_shards():
         lo = kv.null_page_of_shard(sh) + 1
         got = kv.owned_pages(s)
         assert got == list(range(lo, lo + len(got)))
+
+
+def test_unused_block_table_entries_rebase_inside_the_shard():
+    """Rows with unused trailing entries — fresh, grown, truncated,
+    released — never hold an id outside their shard's block: the
+    sharded decode kernel fetches a page for every entry, so an id
+    below the block (the old global null page 0 on shard 1) would be
+    an out-of-bounds read on the chip."""
+    kv = _kv(n_pages=16, page_size=4, max_seqs=4, n_shards=2,
+             max_pages_per_seq=4)
+    for s in range(kv.max_seqs):      # fresh rows: all reserve
+        _check_row_in_block(kv, s)
+    a = kv.alloc_slot(shard=1)
+    b = kv.alloc_slot(shard=1)
+    kv.ensure(a, 6)                    # 2 of 4 entries used
+    kv.ensure(b, 13)                   # 4 of 4
+    assert kv.block_tables[a, 2] == kv.null_page_of_shard(1) != 0
+    _check_shard_invariants(kv)
+    kv.truncate(b, 5)                  # back to 2 pages
+    _check_shard_invariants(kv)
+    kv.release(a)
+    _check_shard_invariants(kv)
+    kv.compact()
+    _check_shard_invariants(kv)
 
 
 def test_pick_shard_prefers_free_pages():

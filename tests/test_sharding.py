@@ -130,8 +130,10 @@ cfg = smoke_config("qwen3-0.6b").replace(dtype="float32", d_model=64,
 p = init_params(cfg, jax.random.PRNGKey(0))
 toks = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, cfg.vocab_size)
 want, _ = forward(cfg, p, toks)
-mesh = jax.make_mesh((2, 2), ("data", "model"))
-with mesh:
+from repro.dist.context import mesh_context
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2), ("data", "model"))
+with mesh_context(mesh):
     psh = params_shardings(cfg, p, mesh)
     pp = jax.device_put(p, psh)
     f = jax.jit(lambda p_, t_: forward(cfg, p_, t_)[0], in_shardings=(psh, None))
