@@ -275,6 +275,21 @@ def main():
     print(f"served {len(reqs)} requests, {eng.stats['tokens']} tokens, "
           f"decode throughput {tput:.1f} tok/s on {dev.platform} "
           f"({dev.device_kind})")
+    st = eng.stats
+    ticks = st["ticks"]
+    if ticks:
+        split = " / ".join(
+            f"{1e3 * st.get(f'serve.decode.{k}_s', 0.0) / ticks:.2f}"
+            for k in ("grow", "step", "readback", "emit"))
+        print(f"per decode tick (ms, {ticks} ticks): grow / step / "
+              f"readback / emit {split}; {st['decode_rows'] / ticks:.1f} "
+              f"of {eng.B} rows live, "
+              f"{st.get('bt_rows_synced', 0) / ticks:.2f} block-table "
+              f"rows pushed")
+    if st.get("admit_prompt_tokens"):
+        print(f"prefix cache: {st['admit_prefix_tokens']} of "
+              f"{st['admit_prompt_tokens']} admitted prompt tokens "
+              f"attached from the index")
     for r in reqs[:3]:
         print(" ", repr(tok.decode(r.out)))
 

@@ -50,6 +50,7 @@ from repro.models.model import init_cache
 from repro.serve import compile_cache
 from repro.serve.kv_cache import PagedKVCache
 from repro.serve.scheduler import Scheduler
+from repro.serve.trace import Recorder
 
 MIN_BUCKET = 8
 
@@ -297,10 +298,20 @@ class ServeEngine:
                                                     ())))
 
         self.prefill_chunk = prefill_chunk
+        # raw accumulators (hot path); `stats_snapshot()` freezes them
+        # plus the pool/index/compile-cache counters into an EngineStats.
+        # The recorder's spans and counters (serve/trace.py) land here
+        # too, as flat `<span>_s` / `<span>_n` and counter keys;
+        # `prefill_s` and `decode_s` are the totals of the spans that
+        # feed them.
+        self.stats = {"prefill_s": 0.0, "decode_s": 0.0, "tokens": 0,
+                      "ticks": 0, "prefill_tokens": 0,
+                      "draft_tokens": 0, "accepted_tokens": 0}
+        self.recorder = Recorder(self.stats)
         self.sched = Scheduler(
             self.kv, watermark=watermark if cache_kind == "paged" else 0,
             prefill_chunk=prefill_chunk, prefix=self._prefix,
-            slab=self.slab)
+            slab=self.slab, recorder=self.recorder)
         self.pos = np.zeros((batch_size,), np.int32)
         self.cur = np.zeros((batch_size,), np.int32)
         self._prefill = compile_cache.get("prefill", cfg, mesh)
@@ -328,11 +339,6 @@ class ServeEngine:
                         "an explicit draft_params tree")
                 draft_params = make_draft_params(params, self.draft_bits)
             self.draft_params = draft_params
-        # raw accumulators (hot path); `stats_snapshot()` freezes them
-        # plus the pool/index/compile-cache counters into an EngineStats
-        self.stats = {"prefill_s": 0.0, "decode_s": 0.0, "tokens": 0,
-                      "ticks": 0, "prefill_tokens": 0,
-                      "draft_tokens": 0, "accepted_tokens": 0}
         self._entries = []
 
     def stats_snapshot(self):
@@ -379,6 +385,7 @@ class ServeEngine:
                  if self._bt_applied[s] != self.kv.bt_version[s]]
         if not dirty:
             return
+        self.recorder.count("bt_rows_synced", len(dirty))
         idx = pad_pow2(dirty, dirty[-1])
         rows = self.kv.block_tables[idx]
         self._bt_dev = self._bt_update(self._bt_dev,
@@ -396,107 +403,108 @@ class ServeEngine:
         return padded, L
 
     def _admit(self, e):
-        t0 = time.time()
-        if e.shared_tokens:
-            # attach the matched prefix pages by reference BEFORE any
-            # allocation: the attach pins them (refcount >= 2) against
-            # the allocator's index reclaim
-            self.kv.share(e.slot, e.shared_pages)
-            e.prefilled = e.shared_tokens
-        if self.prefill_chunk:
-            # chunked mode: admission only reserves the slot (plus any
-            # shared prefix); prompt tokens flow through _prefill_tick
-            # one chunk per engine tick
-            self.pos[e.slot] = 0
-            self.stats["prefill_s"] += time.time() - t0
-            return
-        L = len(e.prompt)
-        if e.shared_tokens:
-            # prefix hit: prefill only the unshared suffix through the
-            # extend path; the COW fork (if the match ends mid-page)
-            # happens before the suffix K/V lands in pages
-            N = e.shared_tokens
-            suffix = e.prompt[N:]
-            nv = len(suffix)
-            C = bucket_len(nv, self.max_len) if self._bucket else nv
-            padded = np.zeros((C,), np.int32)
-            padded[:nv] = suffix
-            self.kv.ensure(e.slot, L)
-            self._apply_copies(self.kv.cow_for_write(e.slot, N, L))
-            bt = self._bt_slice(e.slot, L)
-            logits, self.cache = self._extend(
-                self.params, self.cache,
-                jnp.asarray(padded[None], jnp.int32),
-                jnp.asarray([N], jnp.int32), bt,
-                jnp.asarray([nv], jnp.int32))
-            self.stats["prefill_tokens"] += nv
-            self._emit_first_token(e, logits, L)
-            self.stats["prefill_s"] += time.time() - t0
-            return
-        padded, L = self._padded_prompt(e.prompt)
-        tokens = jnp.asarray(padded[None, :], jnp.int32)
-        last = jnp.asarray([L - 1], jnp.int32)
-        self.stats["prefill_tokens"] += L
-        if self._extend_prefill:
-            # sliding-window layers: write the prompt at absolute page
-            # slots via one whole-prompt extend step
-            self.kv.ensure(e.slot, L)
-            bt = self._bt_slice(e.slot, L)
-            logits, self.cache = self._extend(
-                self.params, self.cache, tokens,
-                jnp.asarray([0], jnp.int32), bt,
-                jnp.asarray([L], jnp.int32))
-            self._emit_first_token(e, logits, L)
-            self.stats["prefill_s"] += time.time() - t0
-            return
-        if self.cache_kind == "paged":
-            self.kv.ensure(e.slot, L)
-            last_logits, row_cache = self._prefill(self.params, tokens,
-                                                   last, len(padded))
-            npg = -(-len(padded) // self.page_size)
-            ids = self.kv.owned_pages(e.slot)
-            # reserve-page pad (masked out), inside the slot's shard
-            ids = (ids + [self.kv.null_page_of_slot(e.slot)] * npg)[:npg]
-            self.cache = self._scatter(self.cache, row_cache,
-                                       jnp.int32(e.slot),
-                                       jnp.asarray(ids, jnp.int32),
-                                       jnp.int32(L))
-        else:
-            last_logits, cache1 = self._prefill(self.params, tokens, last,
-                                                self.max_len)
-            slot = e.slot
+        span = self.recorder.span
+        with span("serve.admit", e.rid, feeds="prefill_s"):
+            if e.shared_tokens:
+                # attach the matched prefix pages by reference BEFORE any
+                # allocation: the attach pins them (refcount >= 2) against
+                # the allocator's index reclaim
+                self.kv.share(e.slot, e.shared_pages)
+                e.prefilled = e.shared_tokens
+            if self.prefill_chunk:
+                # chunked mode: admission only reserves the slot (plus any
+                # shared prefix); prompt tokens flow through _prefill_tick
+                # one chunk per engine tick
+                self.pos[e.slot] = 0
+                return
+            L = len(e.prompt)
+            if e.shared_tokens:
+                # prefix hit: prefill only the unshared suffix through the
+                # extend path; the COW fork (if the match ends mid-page)
+                # happens before the suffix K/V lands in pages
+                N = e.shared_tokens
+                suffix = e.prompt[N:]
+                nv = len(suffix)
+                C = bucket_len(nv, self.max_len) if self._bucket else nv
+                padded = np.zeros((C,), np.int32)
+                padded[:nv] = suffix
+                with span("serve.admit.cow"):
+                    self.kv.ensure(e.slot, L)
+                    self._apply_copies(self.kv.cow_for_write(e.slot, N, L))
+                with span("serve.admit.forward", nv):
+                    bt = self._bt_slice(e.slot, L)
+                    logits, self.cache = self._extend(
+                        self.params, self.cache,
+                        jnp.asarray(padded[None], jnp.int32),
+                        jnp.asarray([N], jnp.int32), bt,
+                        jnp.asarray([nv], jnp.int32))
+                self.stats["prefill_tokens"] += nv
+                self._emit_first_token(e, logits, L)
+                return
+            padded, L = self._padded_prompt(e.prompt)
+            self.stats["prefill_tokens"] += L
+            if self.cache_kind == "paged":
+                with span("serve.admit.cow"):
+                    self.kv.ensure(e.slot, L)
+            with span("serve.admit.forward", L):
+                tokens = jnp.asarray(padded[None, :], jnp.int32)
+                last = jnp.asarray([L - 1], jnp.int32)
+                if self._extend_prefill:
+                    # sliding-window layers: write the prompt at absolute
+                    # page slots via one whole-prompt extend step
+                    bt = self._bt_slice(e.slot, L)
+                    last_logits, self.cache = self._extend(
+                        self.params, self.cache, tokens,
+                        jnp.asarray([0], jnp.int32), bt,
+                        jnp.asarray([L], jnp.int32))
+                elif self.cache_kind == "paged":
+                    last_logits, row_cache = self._prefill(
+                        self.params, tokens, last, len(padded))
+                    npg = -(-len(padded) // self.page_size)
+                    ids = self.kv.owned_pages(e.slot)
+                    # reserve-page pad (masked out), inside the slot's shard
+                    ids = (ids + [self.kv.null_page_of_slot(e.slot)]
+                           * npg)[:npg]
+                    self.cache = self._scatter(self.cache, row_cache,
+                                               jnp.int32(e.slot),
+                                               jnp.asarray(ids, jnp.int32),
+                                               jnp.int32(L))
+                else:
+                    last_logits, cache1 = self._prefill(
+                        self.params, tokens, last, self.max_len)
+                    slot = e.slot
 
-            def merge(batch_leaf, one_leaf):
-                # leaves: (G, B, ...) vs (G, 1, ...)
-                return batch_leaf.at[:, slot].set(one_leaf[:, 0])
-            self.cache = jax.tree.map(merge, self.cache, cache1)
-        self._emit_first_token(e, last_logits, L)
-        self.stats["prefill_s"] += time.time() - t0
+                    def merge(batch_leaf, one_leaf):
+                        # leaves: (G, B, ...) vs (G, 1, ...)
+                        return batch_leaf.at[:, slot].set(one_leaf[:, 0])
+                    self.cache = jax.tree.map(merge, self.cache, cache1)
+            self._emit_first_token(e, last_logits, L)
 
     def _emit_first_token(self, e, last_logits, prompt_len):
-        tok = int(jnp.argmax(last_logits[0]))
-        e.req.out.append(tok)
-        if not e.metrics.t_first_token:
-            e.metrics.t_first_token = time.time()
-        self.pos[e.slot] = prompt_len
-        self.cur[e.slot] = tok
-        e.prefilled = prompt_len
-        if self._prefix is not None:
-            # index the prompt's full pages right away so concurrent
-            # same-prefix requests share them; these pages are never
-            # written again (decode lands at positions >= prompt_len).
-            # The partial tail page is indexed at finish() instead —
-            # indexing it now would force a COW fork on the very next
-            # decode token.
-            nfull = prompt_len // self.page_size
-            if nfull:
-                self._prefix.insert(
-                    np.asarray(e.prompt[:nfull * self.page_size]),
-                    self.kv.owned_pages(e.slot)[:nfull])
-        # the prefill-produced token can already satisfy the request
-        if (len(e.req.out) >= e.req.max_new_tokens
-                or (e.req.eos is not None and tok == e.req.eos)):
-            self._finish(e)
+        with self.recorder.span("serve.admit.first_token", e.rid):
+            tok = int(jnp.argmax(last_logits[0]))
+            e.req.out.append(tok)
+            if not e.metrics.t_first_token:
+                e.metrics.t_first_token = time.time()
+            self.pos[e.slot] = prompt_len
+            self.cur[e.slot] = tok
+            e.prefilled = prompt_len
+            if self._prefix is not None:
+                # index the prompt's full pages right away so concurrent
+                # same-prefix requests share them; these pages are never
+                # written again (decode lands at positions >= prompt_len).
+                # The partial tail page is indexed at finish() instead —
+                # indexing it now would force a COW fork on the very next
+                # decode token.
+                nfull = prompt_len // self.page_size
+                if nfull:
+                    self._prefix.insert(
+                        np.asarray(e.prompt[:nfull * self.page_size]),
+                        self.kv.owned_pages(e.slot)[:nfull])
+            # the prefill-produced token can already satisfy the request
+            if (len(e.req.out) >= e.req.max_new_tokens
+                    or (e.req.eos is not None and tok == e.req.eos)):
+                self._finish(e)
 
     def _finish(self, e):
         """Complete a request, handing the tokens whose KV its pages
@@ -531,27 +539,28 @@ class ServeEngine:
         if not pending:
             return
         e = min(pending, key=lambda x: x.metrics.t_admit)
-        t0 = time.time()
         C = self.prefill_chunk
         s = e.prefilled
         chunk = e.prompt[s:s + C]
         nv = len(chunk)
-        padded = np.zeros((C,), np.int32)
-        padded[:nv] = chunk
         ok, copies = self.sched.ensure_write_capacity(e.slot, s, s + nv)
         if not ok:
             return    # evicted while growing; it will be re-admitted
-        self._apply_copies(copies)
-        bt = self._bt_slice(e.slot, s + nv)
-        logits, self.cache = self._extend(
-            self.params, self.cache, jnp.asarray(padded[None], jnp.int32),
-            jnp.asarray([s], jnp.int32), bt,
-            jnp.asarray([nv], jnp.int32))
-        e.prefilled = s + nv
-        self.stats["prefill_tokens"] += nv
-        if e.prefilled >= len(e.prompt):
-            self._emit_first_token(e, logits, len(e.prompt))
-        self.stats["prefill_s"] += time.time() - t0
+        with self.recorder.span("serve.prefill_chunk", e.rid,
+                                feeds="prefill_s"):
+            padded = np.zeros((C,), np.int32)
+            padded[:nv] = chunk
+            self._apply_copies(copies)
+            bt = self._bt_slice(e.slot, s + nv)
+            logits, self.cache = self._extend(
+                self.params, self.cache,
+                jnp.asarray(padded[None], jnp.int32),
+                jnp.asarray([s], jnp.int32), bt,
+                jnp.asarray([nv], jnp.int32))
+            e.prefilled = s + nv
+            self.stats["prefill_tokens"] += nv
+            if e.prefilled >= len(e.prompt):
+                self._emit_first_token(e, logits, len(e.prompt))
 
     # ---------------- decode ----------------
     def _decode_ready(self):
@@ -564,53 +573,62 @@ class ServeEngine:
         ready = self._decode_ready()
         if not ready:
             return
-        if self.cache_kind == "paged":
-            grown = []
-            for slot in ready:
-                if slot not in self.sched.running:
-                    continue    # evicted while growing an earlier slot
-                # the new token lands at pos -> need pos+1 capacity, and
-                # a COW fork if that page is shared (its forks must hit
-                # the device pool before this slot is marked ready)
-                p = int(self.pos[slot])
-                ok, copies = self.sched.ensure_write_capacity(slot, p,
-                                                              p + 1)
-                if ok:
-                    self._apply_copies(copies)
-                    grown.append(slot)
-            # a later growth may have evicted an earlier grown slot
-            ready = [s for s in grown if s in self.sched.running]
-            if not ready:
-                return
-        t0 = time.time()
-        toks = jnp.asarray(self.cur[:, None], jnp.int32)
-        pos = jnp.asarray(self.pos, jnp.int32)
-        if self.cache_kind == "paged":
-            self._sync_block_tables()
-            live = np.zeros((self.B,), np.int32)
-            live[ready] = 1         # masked rows write to the null page
-            logits, self.cache = self._decode(self.params, self.cache,
-                                              toks, pos, self._bt_dev,
-                                              jnp.asarray(live),
-                                              self._null_row)
-        else:
-            logits, self.cache = self._decode(self.params, self.cache,
-                                              toks, pos)
-        logits.block_until_ready()
-        self.stats["decode_s"] += time.time() - t0
-        self.stats["ticks"] += 1
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        for slot in ready:
-            e = self.sched.running[slot]
-            self.stats["tokens"] += 1
-            tok = int(nxt[slot])
-            e.req.out.append(tok)
-            self.pos[slot] += 1
-            self.cur[slot] = tok
-            hit_eos = e.req.eos is not None and tok == e.req.eos
-            if (len(e.req.out) >= e.req.max_new_tokens or hit_eos
-                    or self.pos[slot] >= self._seq_cap() - 1):
-                self._finish(e)
+        span = self.recorder.span
+        with span("serve.decode.tick", len(ready)):
+            if self.cache_kind == "paged":
+                with span("serve.decode.grow"):
+                    grown = []
+                    for slot in ready:
+                        if slot not in self.sched.running:
+                            continue    # evicted while growing an earlier slot
+                        # the new token lands at pos -> need pos+1 capacity,
+                        # and a COW fork if that page is shared (its forks
+                        # must hit the device pool before this slot is
+                        # marked ready)
+                        p = int(self.pos[slot])
+                        ok, copies = self.sched.ensure_write_capacity(
+                            slot, p, p + 1)
+                        if ok:
+                            self._apply_copies(copies)
+                            grown.append(slot)
+                    # a later growth may have evicted an earlier grown slot
+                    ready = [s for s in grown if s in self.sched.running]
+                if not ready:
+                    return
+            self.recorder.count("decode_rows", len(ready))
+            with span("serve.decode.step", len(ready), feeds="decode_s"):
+                if self.cache_kind == "paged":
+                    with span("serve.decode.bt_sync"):
+                        self._sync_block_tables()
+                with span("serve.decode.dispatch"):
+                    toks = jnp.asarray(self.cur[:, None], jnp.int32)
+                    pos = jnp.asarray(self.pos, jnp.int32)
+                    if self.cache_kind == "paged":
+                        live = np.zeros((self.B,), np.int32)
+                        live[ready] = 1   # masked rows write to the null page
+                        logits, self.cache = self._decode(
+                            self.params, self.cache, toks, pos,
+                            self._bt_dev, jnp.asarray(live), self._null_row)
+                    else:
+                        logits, self.cache = self._decode(
+                            self.params, self.cache, toks, pos)
+                with span("serve.decode.wait"):
+                    logits.block_until_ready()
+            self.stats["ticks"] += 1
+            with span("serve.decode.readback"):
+                nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            with span("serve.decode.emit"):
+                for slot in ready:
+                    e = self.sched.running[slot]
+                    self.stats["tokens"] += 1
+                    tok = int(nxt[slot])
+                    e.req.out.append(tok)
+                    self.pos[slot] += 1
+                    self.cur[slot] = tok
+                    hit_eos = e.req.eos is not None and tok == e.req.eos
+                    if (len(e.req.out) >= e.req.max_new_tokens or hit_eos
+                            or self.pos[slot] >= self._seq_cap() - 1):
+                        self._finish(e)
 
     # ---------------- speculative decode ----------------
     def _spec_decode_tick(self):
@@ -633,106 +651,119 @@ class ServeEngine:
         ready = self._decode_ready()
         if not ready:
             return
-        k_eff = {}
-        grown = []
-        for slot in ready:
-            if slot not in self.sched.running:
-                continue    # evicted while growing an earlier slot
-            p = int(self.pos[slot])
-            # clamp speculation depth at the sequence capacity: the
-            # verify pass writes k_eff+1 positions starting at pos
-            ke = min(k, cap - 1 - p)
-            ok, copies = self.sched.ensure_write_capacity(
-                slot, p, p + ke + 1)
-            if ok:
-                self._apply_copies(copies)
-                k_eff[slot] = ke
-                grown.append(slot)
-        ready = [s for s in grown if s in self.sched.running]
-        if not ready:
-            return
-        t0 = time.time()
-        self._sync_block_tables()
-        base_pos = self.pos.copy()
+        span = self.recorder.span
+        with span("serve.decode.tick", len(ready)):
+            k_eff = {}
+            with span("serve.decode.grow"):
+                grown = []
+                for slot in ready:
+                    if slot not in self.sched.running:
+                        continue    # evicted while growing an earlier slot
+                    p = int(self.pos[slot])
+                    # clamp speculation depth at the sequence capacity: the
+                    # verify pass writes k_eff+1 positions starting at pos
+                    ke = min(k, cap - 1 - p)
+                    ok, copies = self.sched.ensure_write_capacity(
+                        slot, p, p + ke + 1)
+                    if ok:
+                        self._apply_copies(copies)
+                        k_eff[slot] = ke
+                        grown.append(slot)
+                ready = [s for s in grown if s in self.sched.running]
+            if not ready:
+                return
+            self.recorder.count("decode_rows", len(ready))
+            with span("serve.decode.step", len(ready), feeds="decode_s"):
+                self._sync_block_tables()
+                base_pos = self.pos.copy()
 
-        # ---- propose: ONE fused k-step draft pass (on-device argmax
-        # feedback loop, models/model.py:draft_propose_paged) instead of
-        # k host round-trips — the per-step dispatch + transfer overhead
-        # used to dominate the tick and cancel the speculation gain.
-        # Rows whose clamped depth is exhausted (k_eff <= j) write to
-        # their shard's null page at position 0, like any inactive row.
-        ke_arr = np.zeros((self.B,), np.int32)
-        for s in ready:
-            ke_arr[s] = k_eff[s]
-        dt_dev, self.cache = self._draft_propose(
-            self.draft_params, self.cache,
-            jnp.asarray(self.cur, jnp.int32),
-            jnp.asarray(base_pos, jnp.int32), self._bt_dev,
-            jnp.asarray(ke_arr), self._null_row, k)
+                # ---- propose: ONE fused k-step draft pass (on-device
+                # argmax feedback loop, models/model.py:draft_propose_paged)
+                # instead of k host round-trips — the per-step dispatch +
+                # transfer overhead used to dominate the tick and cancel
+                # the speculation gain. Rows whose clamped depth is
+                # exhausted (k_eff <= j) write to their shard's null page
+                # at position 0, like any inactive row.
+                ke_arr = np.zeros((self.B,), np.int32)
+                for s in ready:
+                    ke_arr[s] = k_eff[s]
+                dt_dev, self.cache = self._draft_propose(
+                    self.draft_params, self.cache,
+                    jnp.asarray(self.cur, jnp.int32),
+                    jnp.asarray(base_pos, jnp.int32), self._bt_dev,
+                    jnp.asarray(ke_arr), self._null_row, k)
 
-        # ---- verify: one batched target pass over k+1 positions; the
-        # verify tokens are assembled on device so draft tokens never
-        # round-trip through the host before verify is dispatched
-        verify_toks = jnp.concatenate(
-            [jnp.asarray(self.cur[:, None], jnp.int32), dt_dev], axis=1)
-        live = np.zeros((self.B,), np.int32)
-        live[ready] = 1
-        n_valid = np.zeros((self.B,), np.int32)
-        for s in ready:
-            n_valid[s] = k_eff[s] + 1
-        logits_all, self.cache = self._verify(
-            self.params, self.cache, verify_toks,
-            jnp.asarray(np.where(live > 0, base_pos, 0), jnp.int32),
-            self._bt_dev, jnp.asarray(n_valid), jnp.asarray(live),
-            self._null_row)
-        draft_toks = np.asarray(dt_dev)                        # (B, k)
-        greedy = np.asarray(jnp.argmax(logits_all, axis=-1))   # (B, k+1)
-        probs = (np.asarray(jax.nn.softmax(logits_all, axis=-1))
-                 if self.accept_rule == "typical" else None)
-        self.stats["decode_s"] += time.time() - t0
-        self.stats["ticks"] += 1
+                # ---- verify: one batched target pass over k+1
+                # positions; the verify tokens are assembled on device so
+                # draft tokens never round-trip through the host before
+                # verify is dispatched
+                verify_toks = jnp.concatenate(
+                    [jnp.asarray(self.cur[:, None], jnp.int32), dt_dev],
+                    axis=1)
+                live = np.zeros((self.B,), np.int32)
+                live[ready] = 1
+                n_valid = np.zeros((self.B,), np.int32)
+                for s in ready:
+                    n_valid[s] = k_eff[s] + 1
+                logits_all, self.cache = self._verify(
+                    self.params, self.cache, verify_toks,
+                    jnp.asarray(np.where(live > 0, base_pos, 0), jnp.int32),
+                    self._bt_dev, jnp.asarray(n_valid), jnp.asarray(live),
+                    self._null_row)
+                draft_toks = np.asarray(dt_dev)                  # (B, k)
+                greedy = np.asarray(
+                    jnp.argmax(logits_all, axis=-1))             # (B, k+1)
+                probs = (np.asarray(jax.nn.softmax(logits_all, axis=-1))
+                         if self.accept_rule == "typical" else None)
+            self.stats["ticks"] += 1
 
-        # ---- accept
-        for slot in ready:
-            e = self.sched.running[slot]
-            ke = k_eff[slot]
-            dt, g = draft_toks[slot], greedy[slot]
-            self.stats["draft_tokens"] += ke
-            m = 0
-            if probs is not None:
-                # typical acceptance: keep a draft token the target
-                # gives at least typical_tau of its own argmax mass
-                while m < ke:
-                    pm = probs[slot, m]
-                    if pm[dt[m]] < self.typical_tau * pm.max():
-                        break
-                    m += 1
-            else:
-                while m < ke and dt[m] == g[m]:
-                    m += 1
-            self.stats["accepted_tokens"] += m
-            # accepted draft prefix + the target's token at position m
-            # (correction on mismatch, bonus after full acceptance) —
-            # emitted one by one under the vanilla stop conditions
-            burst = [int(dt[j]) for j in range(m)] + [int(g[m])]
-            emitted, fin = 0, False
-            for tok in burst:
-                e.req.out.append(tok)
-                self.stats["tokens"] += 1
-                emitted += 1
-                hit_eos = e.req.eos is not None and tok == e.req.eos
-                if (len(e.req.out) >= e.req.max_new_tokens or hit_eos
-                        or int(base_pos[slot]) + emitted >= cap - 1):
-                    fin = True
+            # ---- accept
+            with span("serve.decode.emit"):
+                for slot in ready:
+                    self._accept(slot, k_eff[slot], draft_toks[slot],
+                                 greedy[slot], probs, int(base_pos[slot]),
+                                 cap)
+
+    def _accept(self, slot, ke, dt, g, probs, base, cap) -> None:
+        """Emit the accepted draft prefix of one row plus the target's
+        token after it, and roll the row's pages back to what it kept."""
+        e = self.sched.running[slot]
+        self.stats["draft_tokens"] += ke
+        m = 0
+        if probs is not None:
+            # typical acceptance: keep a draft token the target
+            # gives at least typical_tau of its own argmax mass
+            while m < ke:
+                pm = probs[slot, m]
+                if pm[dt[m]] < self.typical_tau * pm.max():
                     break
-            new_pos = int(base_pos[slot]) + emitted
-            self.pos[slot] = new_pos
-            self.cur[slot] = burst[emitted - 1]
-            # rollback: KV is cached for [0, new_pos); whole pages past
-            # that point return to the pool (or to their other readers)
-            self.kv.truncate(slot, new_pos)
-            if fin:
-                self._finish(e)
+                m += 1
+        else:
+            while m < ke and dt[m] == g[m]:
+                m += 1
+        self.stats["accepted_tokens"] += m
+        # accepted draft prefix + the target's token at position m
+        # (correction on mismatch, bonus after full acceptance) —
+        # emitted one by one under the vanilla stop conditions
+        burst = [int(dt[j]) for j in range(m)] + [int(g[m])]
+        emitted, fin = 0, False
+        for tok in burst:
+            e.req.out.append(tok)
+            self.stats["tokens"] += 1
+            emitted += 1
+            hit_eos = e.req.eos is not None and tok == e.req.eos
+            if (len(e.req.out) >= e.req.max_new_tokens or hit_eos
+                    or base + emitted >= cap - 1):
+                fin = True
+                break
+        new_pos = base + emitted
+        self.pos[slot] = new_pos
+        self.cur[slot] = burst[emitted - 1]
+        # rollback: KV is cached for [0, new_pos); whole pages past
+        # that point return to the pool (or to their other readers)
+        self.kv.truncate(slot, new_pos)
+        if fin:
+            self._finish(e)
 
     # ---------------- engine ----------------
     def _seq_cap(self) -> int:

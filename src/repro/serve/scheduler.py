@@ -35,6 +35,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from repro.serve.kv_cache import OutOfPages, PagedKVCache
+from repro.serve.trace import Recorder
 
 
 @dataclass
@@ -65,6 +66,7 @@ class _Entry:
     req: object                       # engine Request
     prompt: np.ndarray                # current (possibly extended) prompt
     metrics: RequestMetrics = field(default_factory=RequestMetrics)
+    rid: int = -1                     # request id, assigned at submit
     slot: int = -1
     prefilled: int = 0                # prompt tokens already in pages
     shared_tokens: int = 0            # prefix tokens matched at admission
@@ -76,7 +78,7 @@ class Scheduler:
 
     def __init__(self, kv: PagedKVCache, *, watermark: int = 1,
                  prefill_chunk: int | None = None, prefix=None,
-                 slab=None):
+                 slab=None, recorder: Recorder | None = None):
         self.kv = kv
         self.watermark = int(watermark)
         self.prefill_chunk = prefill_chunk
@@ -89,10 +91,15 @@ class Scheduler:
         self.waiting: deque[_Entry] = deque()
         self.running: dict[int, _Entry] = {}   # slot -> entry
         self.preemptions = 0
+        # spans and counters (serve/trace.py); the engine passes its own
+        self.recorder = recorder if recorder is not None else Recorder()
+        self._next_rid = 0
 
     # ---------------- queue ----------------
     def submit(self, req) -> None:
-        e = _Entry(req=req, prompt=np.asarray(req.prompt, np.int32))
+        e = _Entry(req=req, prompt=np.asarray(req.prompt, np.int32),
+                   rid=self._next_rid)
+        self._next_rid += 1
         e.metrics.t_submit = time.time()
         e.metrics.n_prompt = len(e.prompt)
         self.waiting.append(e)
@@ -152,6 +159,12 @@ class Scheduler:
         if len(self.running) >= self.kv.max_seqs:
             return None
         e = self.waiting[0]
+        with self.recorder.span("serve.sched.admit", e.rid):
+            return self._admit_head(e)
+
+    def _admit_head(self, e: _Entry) -> _Entry | None:
+        """try_admit's body for the queue head `e`, once a sequence
+        slot is known to be free."""
         resumed = e.metrics.n_preemptions > 0
         shard = self._pick_shard()
         if shard is None:
@@ -204,6 +217,9 @@ class Scheduler:
             e.metrics.n_prefix_tokens += shared_tokens
         e.metrics.t_admit = time.time()
         self.running[slot] = e
+        rec = self.recorder
+        rec.count("admit_prompt_tokens", len(e.prompt))
+        rec.count("admit_prefix_tokens", shared_tokens)
         return e
 
     # ---------------- preemption ----------------
