@@ -192,28 +192,30 @@ _CODED_POOL = ("k_codes", "k_alphas", "k_betas",
                "v_codes", "v_alphas", "v_betas")
 
 
-def paged_decode(q, pool, block_tables, ctx_lens, *, window=None, cap=None):
-    """Single-token attention over a layer's page pool: q (B, Hkv, rep,
-    hd); pool the raw {"k_pages", "v_pages"} or the binary-coded
-    {"k_codes", ...} leaves (models/attention.py:init_paged_kv);
-    block_tables (B, T) global page ids; ctx_lens (B,). Returns
-    (B, Hkv, rep, hd)."""
+def paged_decode(q, pool, block_tables, ctx_lens, layer, *, window=None,
+                 cap=None):
+    """Single-token attention over one layer of the stacked page pool:
+    q (B, Hkv, rep, hd); pool the raw {"k_pages", "v_pages"} or the
+    binary-coded {"k_codes", ...} leaves, each (L, P, page, ...)
+    (models/attention.py:init_paged_kv under the layer stack);
+    block_tables (B, T) global page ids; ctx_lens (B,); layer an int32
+    scalar. Returns (B, Hkv, rep, hd)."""
     coded = "k_codes" in pool
     leaves = [pool[n] for n in (_CODED_POOL if coded else _RAW_POOL)]
     if not use_pallas():
         fn = ref.paged_attention_quant_ref if coded else \
             ref.paged_attention_ref
-        return fn(q, *leaves, block_tables, ctx_lens, window=window,
+        return fn(q, *leaves, block_tables, ctx_lens, layer, window=window,
                   cap=cap)
     kernel = paged_attention_quant if coded else paged_attention
 
-    def run(q, block_tables, ctx_lens, *leaves):
-        return kernel(q, *leaves, block_tables, ctx_lens, window=window,
-                      cap=cap, interpret=interpret())
+    def run(q, block_tables, ctx_lens, layer, *leaves):
+        return kernel(q, *leaves, block_tables, ctx_lens, layer,
+                      window=window, cap=cap, interpret=interpret())
 
     mesh = _mesh()
     if mesh is None:
-        return run(q, block_tables, ctx_lens, *leaves)
+        return run(q, block_tables, ctx_lens, layer, *leaves)
     # the engine's page pool is split into one contiguous block of pages
     # per data shard, serving the batch rows of that shard; block tables
     # hold global ids, so each shard rebases them onto its block. The
@@ -221,23 +223,23 @@ def paged_decode(q, pool, block_tables, ctx_lens, *, window=None, cap=None):
     # allocator keeps every entry of a row, unused ones included, inside
     # the row's block (serve/kv_cache.py), so no rebased id is negative
     B, Hkv = q.shape[:2]
-    n_pages = leaves[0].shape[0]
+    n_pages = leaves[0].shape[1]
     data = _axis(mesh, "data", B)
     if data is not None and n_pages % dict(mesh.shape)["data"]:
         data = None
     heads = _axis(mesh, "model", Hkv)
     block = n_pages // dict(mesh.shape)["data"] if data else 0
 
-    def local(q, block_tables, ctx_lens, *leaves):
+    def local(q, block_tables, ctx_lens, layer, *leaves):
         if data is not None:
             block_tables = block_tables - jax.lax.axis_index(data) * block
-        return run(q, block_tables, ctx_lens, *leaves)
+        return run(q, block_tables, ctx_lens, layer, *leaves)
 
-    page_spec = [P(data, None, heads) if coded else
-                 P(data, None, heads, None)] * len(leaves)
+    page_spec = [P(None, data, None, heads) if coded else
+                 P(None, data, None, heads, None)] * len(leaves)
     return jax.shard_map(
         local, mesh=mesh,
-        in_specs=(P(data, heads, None, None), P(data, None), P(data),
+        in_specs=(P(data, heads, None, None), P(data, None), P(data), P(),
                   *page_spec),
         out_specs=P(data, heads, None, None),
-        check_vma=False)(q, block_tables, ctx_lens, *leaves)
+        check_vma=False)(q, block_tables, ctx_lens, layer, *leaves)

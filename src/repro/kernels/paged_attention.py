@@ -1,11 +1,13 @@
 """Pallas TPU kernel: batched single-token paged-attention decode.
 
-K/V live in a global page pool `(n_pages, page_size, Hkv, hd)` shared by
-every sequence; each sequence owns a row of a block table `(B, T)` of
-page ids (see serve/kv_cache.py). The grid is (batch, pages-per-seq):
-for each sequence the kernel streams its pages HBM->VMEM one per grid
-step — the page id comes from the *scalar-prefetched* block table, so
-the DMA address is known before the body runs — and folds each page
+K/V live in a global page pool shared by every sequence, stacked over
+the model's layer groups: `(n_layers, n_pages, page_size, Hkv, hd)`.
+Each sequence owns a row of a block table `(B, T)` of page ids (see
+serve/kv_cache.py). The grid is (batch, pages-per-seq): for each
+sequence the kernel streams its pages of one layer HBM->VMEM one per
+grid step — the page is addressed as (layer, page id), both from
+*scalar-prefetched* operands, so the DMA address is known before the
+body runs and the stack is never sliced per layer — and folds each page
 into an online-softmax (flash) accumulator held in VMEM scratch. One
 grid row therefore reads exactly ctx_len tokens of K/V instead of a
 dense max_len slab, which is what makes decode bandwidth scale with the
@@ -58,9 +60,9 @@ def _fold(t, ctx, q, k, v, m_ref, l_ref, acc_ref, *, page_size, scale,
     m_ref[...] = m_new
 
 
-def _kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-            acc_ref, *, page_size: int, pages_per_seq: int, scale: float,
-            window, cap):
+def _kernel(bt_ref, cl_ref, ly_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
+            l_ref, acc_ref, *, page_size: int, pages_per_seq: int,
+            scale: float, window, cap):
     b = pl.program_id(0)
     t = pl.program_id(1)
 
@@ -75,7 +77,8 @@ def _kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     @pl.when(t * page_size < ctx)
     def _fold_page():
         _fold(t, ctx, q_ref[0].astype(jnp.float32),
-              k_ref[0].astype(jnp.float32), v_ref[0].astype(jnp.float32),
+              k_ref[0, 0].astype(jnp.float32),
+              v_ref[0, 0].astype(jnp.float32),
               m_ref, l_ref, acc_ref, page_size=page_size, scale=scale,
               window=window, cap=cap)
 
@@ -128,8 +131,8 @@ def _expand_page(codes, alphas, betas, *, n_kv_heads: int, hd: int):
     return jnp.stack(heads)
 
 
-def _kernel_quant(bt_ref, cl_ref, q_ref, kc_ref, ka_ref, kb_ref, vc_ref,
-                  va_ref, vb_ref, o_ref, m_ref, l_ref, acc_ref, *,
+def _kernel_quant(bt_ref, cl_ref, ly_ref, q_ref, kc_ref, ka_ref, kb_ref,
+                  vc_ref, va_ref, vb_ref, o_ref, m_ref, l_ref, acc_ref, *,
                   page_size: int, pages_per_seq: int, scale: float,
                   window, cap, hd: int):
     b = pl.program_id(0)
@@ -146,10 +149,10 @@ def _kernel_quant(bt_ref, cl_ref, q_ref, kc_ref, ka_ref, kb_ref, vc_ref,
     @pl.when(t * page_size < ctx)
     def _fold_page():
         Hkv = q_ref.shape[1]
-        k = _expand_page(kc_ref[0], ka_ref[0], kb_ref[0], n_kv_heads=Hkv,
-                         hd=hd)
-        v = _expand_page(vc_ref[0], va_ref[0], vb_ref[0], n_kv_heads=Hkv,
-                         hd=hd)
+        k = _expand_page(kc_ref[0, 0], ka_ref[0, 0], kb_ref[0, 0],
+                         n_kv_heads=Hkv, hd=hd)
+        v = _expand_page(vc_ref[0, 0], va_ref[0, 0], vb_ref[0, 0],
+                         n_kv_heads=Hkv, hd=hd)
         _fold(t, ctx, q_ref[0].astype(jnp.float32), k, v,
               m_ref, l_ref, acc_ref, page_size=page_size, scale=scale,
               window=window, cap=cap, kv_axes="hpd")
@@ -160,58 +163,63 @@ def _kernel_quant(bt_ref, cl_ref, q_ref, kc_ref, ka_ref, kb_ref, vc_ref,
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("window", "cap", "interpret"))
-def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
-                    window=None, cap=None, interpret=False):
-    """q (B, Hkv, rep, hd); k_pages/v_pages (P, page_size, Hkv, hd);
-    block_tables (B, T) int32 page ids; ctx_lens (B,) int32 live tokens
-    per sequence (including the token just written). Returns
-    (B, Hkv, rep, hd) in q.dtype."""
-    B, Hkv, rep, hd = q.shape
-    _, page_size, _, _ = k_pages.shape
-    T = block_tables.shape[1]
-    scale = hd ** -0.5
+def _page_spec(shape):
+    """One page of one layer of a stacked pool (L, P) + shape, addressed
+    by the prefetched layer and block-table entry."""
+    return pl.BlockSpec((1, 1) + shape, lambda b, t, bt, cl, ly:
+                        (ly[0], bt[b, t]) + (0,) * len(shape))
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+
+def _grid_spec(B, T, Hkv, rep, hd, pools):
+    row = pl.BlockSpec((1, Hkv, rep, hd),
+                       lambda b, t, bt, cl, ly: (b, 0, 0, 0))
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
         grid=(B, T),
-        in_specs=[
-            pl.BlockSpec((1, Hkv, rep, hd),
-                         lambda b, t, bt, cl: (b, 0, 0, 0)),
-            pl.BlockSpec((1, page_size, Hkv, hd),
-                         lambda b, t, bt, cl: (bt[b, t], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, Hkv, hd),
-                         lambda b, t, bt, cl: (bt[b, t], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, Hkv, rep, hd),
-                               lambda b, t, bt, cl: (b, 0, 0, 0)),
+        in_specs=[row, *(_page_spec(a.shape[2:]) for a in pools)],
+        out_specs=row,
         scratch_shapes=[
             pltpu.VMEM((Hkv, rep), jnp.float32),       # running max
             pltpu.VMEM((Hkv, rep), jnp.float32),       # running denom
             pltpu.VMEM((Hkv, rep, hd), jnp.float32),   # weighted acc
         ],
     )
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("window", "cap", "interpret"))
+def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens, layer, *,
+                    window=None, cap=None, interpret=False):
+    """q (B, Hkv, rep, hd); k_pages/v_pages the stacked pools
+    (L, P, page_size, Hkv, hd); block_tables (B, T) int32 page ids;
+    ctx_lens (B,) int32 live tokens per sequence (including the token
+    just written); layer: int32 scalar, the pool layer to read.
+    Returns (B, Hkv, rep, hd) in q.dtype."""
+    B, Hkv, rep, hd = q.shape
+    page_size = k_pages.shape[2]
+    T = block_tables.shape[1]
     return pl.pallas_call(
         functools.partial(_kernel, page_size=page_size, pages_per_seq=T,
-                          scale=scale, window=window, cap=cap),
-        grid_spec=grid_spec,
+                          scale=hd ** -0.5, window=window, cap=cap),
+        grid_spec=_grid_spec(B, T, Hkv, rep, hd, (k_pages, v_pages)),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rep, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(block_tables, ctx_lens, q, k_pages, v_pages)
+    )(block_tables, ctx_lens, jnp.reshape(layer, (1,)).astype(jnp.int32),
+      q, k_pages, v_pages)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("window", "cap", "interpret"))
 def paged_attention_quant(q, k_codes, k_alphas, k_betas, v_codes,
-                          v_alphas, v_betas, block_tables, ctx_lens, *,
-                          window=None, cap=None, interpret=False):
+                          v_alphas, v_betas, block_tables, ctx_lens, layer,
+                          *, window=None, cap=None, interpret=False):
     """Fused-dequant paged decode over a binary-coded page pool
-    (quant/kv.py pool rows): q (B, Hkv, rep, hd); codes
-    (P, page, Hkv*bits*hd/32) u32; alphas (P, page, Hkv*G*bits); betas
-    (P, page, Hkv*G); block_tables (B, T); ctx_lens (B,).
+    (quant/kv.py pool rows, stacked over layers): q (B, Hkv, rep, hd);
+    codes (L, P, page, Hkv*bits*hd/32) u32; alphas (L, P, page,
+    Hkv*G*bits); betas (L, P, page, Hkv*G); block_tables (B, T);
+    ctx_lens (B,); layer: int32 scalar.
 
     Same grid/flash structure as `paged_attention`, but each grid step
     streams a page's *codes + scales* HBM->VMEM (bits/8 + scale bytes
@@ -220,40 +228,17 @@ def paged_attention_quant(q, k_codes, k_alphas, k_betas, v_codes,
     pool: decode is bandwidth-bound, so shrinking the pages shrinks the
     time. Returns (B, Hkv, rep, hd) in q.dtype."""
     B, Hkv, rep, hd = q.shape
-    page_size = k_codes.shape[1]
+    page_size = k_codes.shape[2]
     T = block_tables.shape[1]
-    scale = hd ** -0.5
-
-    def page_spec(shape):
-        return pl.BlockSpec((1,) + shape,
-                            lambda b, t, bt, cl:
-                            (bt[b, t],) + (0,) * len(shape))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, T),
-        in_specs=[
-            pl.BlockSpec((1, Hkv, rep, hd),
-                         lambda b, t, bt, cl: (b, 0, 0, 0)),
-            *(page_spec(a.shape[1:]) for a in (k_codes, k_alphas, k_betas,
-                                               v_codes, v_alphas, v_betas)),
-        ],
-        out_specs=pl.BlockSpec((1, Hkv, rep, hd),
-                               lambda b, t, bt, cl: (b, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Hkv, rep), jnp.float32),       # running max
-            pltpu.VMEM((Hkv, rep), jnp.float32),       # running denom
-            pltpu.VMEM((Hkv, rep, hd), jnp.float32),   # weighted acc
-        ],
-    )
+    pools = (k_codes, k_alphas, k_betas, v_codes, v_alphas, v_betas)
     return pl.pallas_call(
         functools.partial(_kernel_quant, page_size=page_size,
-                          pages_per_seq=T, scale=scale, window=window,
+                          pages_per_seq=T, scale=hd ** -0.5, window=window,
                           cap=cap, hd=hd),
-        grid_spec=grid_spec,
+        grid_spec=_grid_spec(B, T, Hkv, rep, hd, pools),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rep, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(block_tables, ctx_lens, q, k_codes, k_alphas, k_betas,
-      v_codes, v_alphas, v_betas)
+    )(block_tables, ctx_lens, jnp.reshape(layer, (1,)).astype(jnp.int32),
+      q, *pools)

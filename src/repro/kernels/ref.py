@@ -83,16 +83,18 @@ def _paged_attend(q, k, v, ctx_lens, *, window, cap):
     return out.astype(q.dtype)
 
 
-def paged_attention_ref(q, k_pages, v_pages, block_tables, ctx_lens, *,
-                        window=None, cap=None):
-    """q (B, Hkv, rep, hd); k_pages/v_pages (P, page, Hkv, hd);
-    block_tables (B, T); ctx_lens (B,). Returns (B, Hkv, rep, hd)."""
+def paged_attention_ref(q, k_pages, v_pages, block_tables, ctx_lens, layer,
+                        *, window=None, cap=None):
+    """q (B, Hkv, rep, hd); k_pages/v_pages the stacked pools
+    (L, P, page, Hkv, hd), of which layer `layer` (int32 scalar) is
+    read; block_tables (B, T); ctx_lens (B,). Returns (B, Hkv, rep,
+    hd)."""
     B, Hkv, rep, hd = q.shape
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
     T = block_tables.shape[1]
     # gather: (B, T, page, Hkv, hd) -> (B, Hkv, T*page, hd)
-    k = k_pages[block_tables].reshape(B, T * page, Hkv, hd)
-    v = v_pages[block_tables].reshape(B, T * page, Hkv, hd)
+    k = k_pages[layer, block_tables].reshape(B, T * page, Hkv, hd)
+    v = v_pages[layer, block_tables].reshape(B, T * page, Hkv, hd)
     return _paged_attend(q, k.transpose(0, 2, 1, 3),
                          v.transpose(0, 2, 1, 3), ctx_lens,
                          window=window, cap=cap)
@@ -100,23 +102,24 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, ctx_lens, *,
 
 def paged_attention_quant_ref(q, k_codes, k_alphas, k_betas, v_codes,
                               v_alphas, v_betas, block_tables, ctx_lens,
-                              *, window=None, cap=None):
+                              layer, *, window=None, cap=None):
     """Oracle for the fused-dequant kernel, and the non-TPU execution
     path for quantized paged decode: gather each sequence's binary-coded
-    pages through the block table, expand codes -> fp32 K/V
-    (quant/kv.py pool rows: codes (P, page, Hkv*bits*hd/32) u32, alphas
-    (P, page, Hkv*G*bits), betas (P, page, Hkv*G)), then the same
-    masked softmax as paged_attention_ref."""
+    pages of layer `layer` through the block table, expand codes -> fp32
+    K/V (quant/kv.py pool rows, stacked: codes (L, P, page,
+    Hkv*bits*hd/32) u32, alphas (L, P, page, Hkv*G*bits), betas
+    (L, P, page, Hkv*G)), then the same masked softmax as
+    paged_attention_ref."""
     from repro.quant.kv import kv_dequantize, kv_pool_views
 
     B, Hkv, rep, hd = q.shape
-    page = k_codes.shape[1]
+    page = k_codes.shape[2]
     T = block_tables.shape[1]
 
     def pages(codes, alphas, betas):          # -> (B, T, page, Hkv, hd)
         return kv_dequantize(*kv_pool_views(
-            codes[block_tables], alphas[block_tables], betas[block_tables],
-            Hkv))
+            codes[layer, block_tables], alphas[layer, block_tables],
+            betas[layer, block_tables], Hkv))
     k = pages(k_codes, k_alphas, k_betas)
     v = pages(v_codes, v_alphas, v_betas)
     k = k.reshape(B, T * page, Hkv, hd).transpose(0, 2, 1, 3)

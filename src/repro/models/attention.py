@@ -230,11 +230,12 @@ def paged_kv_bits(cache) -> int:
     return cache["k_alphas"].shape[-1] // cache["k_betas"].shape[-1]
 
 
-def _quant_scatter(cache, side, new, pid, off, mask=None):
+def _quant_scatter(cache, side, new, layer, pid, off, mask=None):
     """Quantize-on-write: binary-code `new` K or V vectors (..., hd) and
-    scatter codes+scales into the pool at (pid, off). With `mask`
-    (matching new's leading dims), False rows re-write the null page's
-    slot-0 content instead (the extend path's padding trick)."""
+    scatter codes+scales into the stacked pool at (layer, pid, off).
+    With `mask` (matching new's leading dims), False rows re-write the
+    layer's null-page slot-0 content instead (the extend path's padding
+    trick)."""
     from repro.quant.kv import kv_pool_rows, kv_quantize
     bits = paged_kv_bits(cache)
     G = cache[f"{side}_betas"].shape[-1] // new.shape[-2]
@@ -247,39 +248,44 @@ def _quant_scatter(cache, side, new, pid, off, mask=None):
         pool = cache[name]
         if mask is not None:
             m = mask.reshape(mask.shape + (1,) * (val.ndim - mask.ndim))
-            null = pool[0, 0].reshape(
-                (1,) * mask.ndim + pool.shape[2:])
+            null = pool[layer, 0, 0].reshape(
+                (1,) * mask.ndim + pool.shape[3:])
             val = jnp.where(m, val, null)
-        out[name] = pool.at[pid, off].set(val.astype(pool.dtype))
+        out[name] = pool.at[layer, pid, off].set(val.astype(pool.dtype))
     return out
 
 
-def _gather_dequant(cache, side, block_tables, Hkv, hd):
-    """Gather + expand a sequence's binary-coded pages:
+def _gather_dequant(cache, side, block_tables, layer, Hkv, hd):
+    """Gather + expand a sequence's binary-coded pages of one layer:
     -> (B, T*page, Hkv, hd) fp32 (the extend path's dense view)."""
     from repro.quant.kv import kv_dequantize, kv_pool_views
     bt = block_tables
     B, T = bt.shape
-    page = cache[f"{side}_codes"].shape[1]
-    x = kv_dequantize(*kv_pool_views(cache[f"{side}_codes"][bt],
-                                     cache[f"{side}_alphas"][bt],
-                                     cache[f"{side}_betas"][bt], Hkv))
+    page = cache[f"{side}_codes"].shape[2]
+    x = kv_dequantize(*kv_pool_views(cache[f"{side}_codes"][layer, bt],
+                                     cache[f"{side}_alphas"][layer, bt],
+                                     cache[f"{side}_betas"][layer, bt],
+                                     Hkv))
     return x.reshape(B, T * page, Hkv, hd)
 
 
-def attn_decode_paged(cfg, spec, p, x, cache, block_tables, pos):
+def attn_decode_paged(cfg, spec, p, x, cache, block_tables, pos, layer):
     """Single-token decode against a paged KV pool.
 
-    x: (B, 1, D); cache: {"k_pages","v_pages"} (P, page, Hkv, hd) — or
-    the binary-coded layout {"k_codes","k_alphas","k_betas","v_..."}
-    (init_paged_kv(kv_bits=...)), where the new token's K/V is quantized
+    x: (B, 1, D); cache: the stacked pools {"k_pages","v_pages"}
+    (L, P, page, Hkv, hd) — or the binary-coded layout
+    {"k_codes","k_alphas","k_betas","v_..."} (init_paged_kv(kv_bits=...)
+    under the layer stack), where the new token's K/V is quantized
     before the scatter and the kernel dequantizes inside its accumulator
     loop; block_tables: (B, T) int32 page ids; pos: (B,) absolute
-    positions. Writes the new K/V into page block_tables[b, pos//page]
-    at offset pos%page, then attends over the sequence's gathered pages.
-    Window layers mask by absolute position (no rolling buffer — pages
-    beyond the window stay allocated; the scheduler may reclaim them
-    later). Returns (y, cache)."""
+    positions; layer: int32 scalar, this layer's index in the stack.
+    Writes the new K/V into page (layer, block_tables[b, pos//page]) at
+    offset pos%page, then attends over the sequence's pages of that
+    layer. The stack is never sliced per layer: the model's layer scan
+    carries it and every write lands in place. Window layers mask by
+    absolute position (no rolling buffer — pages beyond the window stay
+    allocated; the scheduler may reclaim them later). Returns (y,
+    cache)."""
     B = x.shape[0]
     hd = cfg.resolved_head_dim
     q, k, v = _project_qkv(cfg, p, x)          # (B,1,H,hd)
@@ -287,17 +293,17 @@ def attn_decode_paged(cfg, spec, p, x, cache, block_tables, pos):
     k = rope(k, pos[:, None], cfg.rope_theta)
 
     quant = paged_kv_bits(cache) > 0
-    page = (cache["k_codes"] if quant else cache["k_pages"]).shape[1]
+    page = (cache["k_codes"] if quant else cache["k_pages"]).shape[2]
     b_idx = jnp.arange(B)
     pid = block_tables[b_idx, pos // page]
     off = pos % page
     if quant:
-        cache = _quant_scatter(cache, "k", k[:, 0], pid, off)
-        cache = _quant_scatter(cache, "v", v[:, 0], pid, off)
+        cache = _quant_scatter(cache, "k", k[:, 0], layer, pid, off)
+        cache = _quant_scatter(cache, "v", v[:, 0], layer, pid, off)
     else:
         kp, vp = cache["k_pages"], cache["v_pages"]
-        kp = kp.at[pid, off].set(k[:, 0].astype(kp.dtype))
-        vp = vp.at[pid, off].set(v[:, 0].astype(vp.dtype))
+        kp = kp.at[layer, pid, off].set(k[:, 0].astype(kp.dtype))
+        vp = vp.at[layer, pid, off].set(v[:, 0].astype(vp.dtype))
         cache = {"k_pages": kp, "v_pages": vp}
 
     qg = q[:, 0].reshape(B, cfg.n_kv_heads,
@@ -306,7 +312,7 @@ def attn_decode_paged(cfg, spec, p, x, cache, block_tables, pos):
     # masked softmax the dense attn_decode computes, so paged and dense
     # engines agree token-for-token on the fp32 CPU tests)
     from repro.kernels import ops
-    out = ops.paged_decode(qg, cache, block_tables, pos + 1,
+    out = ops.paged_decode(qg, cache, block_tables, pos + 1, layer,
                            window=spec.window, cap=cfg.attn_softcap)
     out = out.reshape(B, 1, cfg.n_heads * hd)
     y = linear(out, p["wo"])
@@ -314,12 +320,13 @@ def attn_decode_paged(cfg, spec, p, x, cache, block_tables, pos):
 
 
 def attn_extend_paged(cfg, spec, p, h, cache, block_tables, start_pos,
-                      chunk_mask):
+                      chunk_mask, layer):
     """Chunked-prefill step: C prompt tokens at absolute positions
     start_pos + [0..C) attend causally over everything already in the
-    sequence's pages plus themselves. h: (B, C, D); chunk_mask: (B, C)
-    bool — False marks padding tokens whose K/V must not land in pages.
-    Returns (y, cache)."""
+    sequence's pages plus themselves. h: (B, C, D); cache: the stacked
+    pools, as attn_decode_paged; chunk_mask: (B, C) bool — False marks
+    padding tokens whose K/V must not land in pages; layer: int32
+    scalar. Returns (y, cache)."""
     B, C, _ = h.shape
     hd = cfg.resolved_head_dim
     q, k, v = _project_qkv(cfg, p, h)          # (B,C,H,hd)
@@ -328,7 +335,7 @@ def attn_extend_paged(cfg, spec, p, h, cache, block_tables, start_pos,
     k = rope(k, positions, cfg.rope_theta)
 
     quant = paged_kv_bits(cache) > 0
-    page = (cache["k_codes"] if quant else cache["k_pages"]).shape[1]
+    page = (cache["k_codes"] if quant else cache["k_pages"]).shape[2]
     pid = jnp.take_along_axis(block_tables, positions // page, axis=1)
     off = positions % page
     # masked scatter: padding tokens write to the null page (id 0) slot 0,
@@ -337,20 +344,26 @@ def attn_extend_paged(cfg, spec, p, h, cache, block_tables, start_pos,
     off = jnp.where(chunk_mask, off, 0)
     T = block_tables.shape[1]
     if quant:
-        cache = _quant_scatter(cache, "k", k, pid, off, mask=chunk_mask)
-        cache = _quant_scatter(cache, "v", v, pid, off, mask=chunk_mask)
-        ck = _gather_dequant(cache, "k", block_tables, cfg.n_kv_heads, hd)
-        cv = _gather_dequant(cache, "v", block_tables, cfg.n_kv_heads, hd)
+        cache = _quant_scatter(cache, "k", k, layer, pid, off,
+                               mask=chunk_mask)
+        cache = _quant_scatter(cache, "v", v, layer, pid, off,
+                               mask=chunk_mask)
+        ck = _gather_dequant(cache, "k", block_tables, layer,
+                             cfg.n_kv_heads, hd)
+        cv = _gather_dequant(cache, "v", block_tables, layer,
+                             cfg.n_kv_heads, hd)
     else:
         kp, vp = cache["k_pages"], cache["v_pages"]
         m4 = chunk_mask[:, :, None, None]
-        kw = jnp.where(m4, k.astype(kp.dtype), kp[0, 0][None, None])
-        vw = jnp.where(m4, v.astype(vp.dtype), vp[0, 0][None, None])
-        kp = kp.at[pid, off].set(kw)
-        vp = vp.at[pid, off].set(vw)
+        kw = jnp.where(m4, k.astype(kp.dtype), kp[layer, 0, 0][None, None])
+        vw = jnp.where(m4, v.astype(vp.dtype), vp[layer, 0, 0][None, None])
+        kp = kp.at[layer, pid, off].set(kw)
+        vp = vp.at[layer, pid, off].set(vw)
         cache = {"k_pages": kp, "v_pages": vp}
-        ck = kp[block_tables].reshape(B, T * page, cfg.n_kv_heads, hd)
-        cv = vp[block_tables].reshape(B, T * page, cfg.n_kv_heads, hd)
+        ck = kp[layer, block_tables].reshape(B, T * page, cfg.n_kv_heads,
+                                             hd)
+        cv = vp[layer, block_tables].reshape(B, T * page, cfg.n_kv_heads,
+                                             hd)
     ck = ck.transpose(0, 2, 1, 3)
     cv = cv.transpose(0, 2, 1, 3)
     qg = q.reshape(B, C, cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, hd)

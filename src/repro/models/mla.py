@@ -110,12 +110,13 @@ def init_mla_paged(cfg, n_pages, page_size, dtype):
                                     m.qk_rope_head_dim), dtype)}
 
 
-def _paged_latent_views(cache, block_tables):
-    """Gather a sequence's latent pages into dense (B, T*page, ·) views."""
+def _paged_latent_views(cache, block_tables, layer):
+    """Gather a sequence's latent pages of one layer of the stacked pool
+    into dense (B, T*page, ·) views."""
     B, T = block_tables.shape
-    page = cache["ckv_pages"].shape[1]
-    c_kv = cache["ckv_pages"][block_tables].reshape(B, T * page, -1)
-    k_pe = cache["kpe_pages"][block_tables].reshape(B, T * page, -1)
+    page = cache["ckv_pages"].shape[2]
+    c_kv = cache["ckv_pages"][layer, block_tables].reshape(B, T * page, -1)
+    k_pe = cache["kpe_pages"][layer, block_tables].reshape(B, T * page, -1)
     return c_kv, k_pe
 
 
@@ -139,25 +140,26 @@ def _mla_attend(cfg, p, q_nope, q_pe, c_kv, k_pe, ok):
     return linear(out, p["wo"])
 
 
-def mla_decode_paged(cfg, spec, p, x, cache, block_tables, pos):
-    """Single-token MLA decode against a latent page pool. Writes the
-    new (c_kv, k_pe) into page block_tables[b, pos//page] at offset
-    pos%page, then attends over the gathered latent pages with the
-    up-projection absorbed the way attn_decode_paged expands raw pages.
-    Same block-table/COW/null-page contract as attn_decode_paged."""
+def mla_decode_paged(cfg, spec, p, x, cache, block_tables, pos, layer):
+    """Single-token MLA decode against the stacked latent page pool
+    (L, P, page, 1, ·). Writes the new (c_kv, k_pe) into page
+    (layer, block_tables[b, pos//page]) at offset pos%page, then attends
+    over the gathered latent pages with the up-projection absorbed the
+    way attn_decode_paged expands raw pages. Same block-table/COW/
+    null-page contract as attn_decode_paged."""
     B = x.shape[0]
     q_nope, q_pe = _queries(cfg, p, x, pos[:, None])     # (B,1,H,·)
     c_new, kpe_new = _latent(cfg, p, x, pos[:, None])    # (B,1,·)
-    page = cache["ckv_pages"].shape[1]
+    page = cache["ckv_pages"].shape[2]
     b_idx = jnp.arange(B)
     pid = block_tables[b_idx, pos // page]
     off = pos % page
-    ckv = cache["ckv_pages"].at[pid, off, 0].set(
+    ckv = cache["ckv_pages"].at[layer, pid, off, 0].set(
         c_new[:, 0].astype(cache["ckv_pages"].dtype))
-    kpe = cache["kpe_pages"].at[pid, off, 0].set(
+    kpe = cache["kpe_pages"].at[layer, pid, off, 0].set(
         kpe_new[:, 0].astype(cache["kpe_pages"].dtype))
     cache = {"ckv_pages": ckv, "kpe_pages": kpe}
-    c_kv, k_pe = _paged_latent_views(cache, block_tables)
+    c_kv, k_pe = _paged_latent_views(cache, block_tables, layer)
     S = c_kv.shape[1]
     ok = (jnp.arange(S)[None, :] <= pos[:, None])[:, None, None, :]
     y = _mla_attend(cfg, p, q_nope, q_pe, c_kv, k_pe, ok)
@@ -165,7 +167,7 @@ def mla_decode_paged(cfg, spec, p, x, cache, block_tables, pos):
 
 
 def mla_extend_paged(cfg, spec, p, h, cache, block_tables, start_pos,
-                     chunk_mask):
+                     chunk_mask, layer):
     """Chunked-prefill / verify step for MLA: C tokens at absolute
     positions start_pos + [0..C) write their latents into the
     sequence's pages (padding rows rewrite the null page's slot 0) and
@@ -174,18 +176,20 @@ def mla_extend_paged(cfg, spec, p, h, cache, block_tables, start_pos,
     positions = start_pos[:, None] + jnp.arange(C)[None, :]
     q_nope, q_pe = _queries(cfg, p, h, positions)        # (B,C,H,·)
     c_new, kpe_new = _latent(cfg, p, h, positions)       # (B,C,·)
-    page = cache["ckv_pages"].shape[1]
+    page = cache["ckv_pages"].shape[2]
     pid = jnp.take_along_axis(block_tables, positions // page, axis=1)
     off = positions % page
     pid = jnp.where(chunk_mask, pid, 0)
     off = jnp.where(chunk_mask, off, 0)
     ckv, kpe = cache["ckv_pages"], cache["kpe_pages"]
     m3 = chunk_mask[:, :, None]
-    cw = jnp.where(m3, c_new.astype(ckv.dtype), ckv[0, 0, 0][None, None])
-    kw = jnp.where(m3, kpe_new.astype(kpe.dtype), kpe[0, 0, 0][None, None])
-    cache = {"ckv_pages": ckv.at[pid, off, 0].set(cw),
-             "kpe_pages": kpe.at[pid, off, 0].set(kw)}
-    c_kv, k_pe = _paged_latent_views(cache, block_tables)
+    cw = jnp.where(m3, c_new.astype(ckv.dtype),
+                   ckv[layer, 0, 0, 0][None, None])
+    kw = jnp.where(m3, kpe_new.astype(kpe.dtype),
+                   kpe[layer, 0, 0, 0][None, None])
+    cache = {"ckv_pages": ckv.at[layer, pid, off, 0].set(cw),
+             "kpe_pages": kpe.at[layer, pid, off, 0].set(kw)}
+    c_kv, k_pe = _paged_latent_views(cache, block_tables, layer)
     S = c_kv.shape[1]
     ok = (jnp.arange(S)[None, :] <= positions[:, :, None])[:, None]
     y = _mla_attend(cfg, p, q_nope, q_pe, c_kv, k_pe, ok)

@@ -24,6 +24,7 @@ from repro.models import attention as attn
 from repro.models import mamba as mam
 from repro.models import mla as mla_mod
 from repro.dist.context import constrain_batch
+from repro.hw import LANE
 from repro.models.layers import (cross_entropy, init_linear, init_swiglu,
                                  linear, rmsnorm, softcap, swiglu)
 from repro.models.moe import init_moe, moe_forward
@@ -340,21 +341,56 @@ def prefill(cfg, params, tokens, max_len, *, remat="none", last_pos=None):
     return unembed(cfg, params, x)[:, 0], cache
 
 
+def _carried(layer_cache) -> bool:
+    """Whether the decode scan carries this layer cache whole: a page
+    pool whose every row fills whole TPU lane tiles (the raw pools at
+    head_dim 128, say). XLA's TPU layout assignment holds an array with
+    narrower rows (binary-coded scale rows, head_dim 64, MLA's rotary
+    keys) in a transposed layout; carried, the row-major pages the
+    attention kernel reads would then be re-laid, whole stack, at every
+    layer or at the scan's ends. Such pools go a layer at a time."""
+    return bool(layer_cache) and all(
+        k in PAGE_LEAVES and a.shape[-1] % LANE == 0
+        for k, a in layer_cache.items())
+
+
 def _decode_scan(cfg, params, cache, x, attn_step):
     """Shared single-step decode machinery: scan the group stack, with
-    the attention flavour injected (dense cache / paged pool / MLA)."""
-    def body(x, inp):
-        gp, gc = inp
-        new_gc = {}
+    the attention flavour injected (dense cache / paged pool / MLA).
+
+    Page pools are read and written as (layer, page): `attn_step(spec,
+    p, h, cache, layer)` gets a stack of pools and the index of its
+    layer in it. A `_carried` pool rides the scan's carry as the whole
+    (n_groups, P, ...) stack, so each layer writes its new tokens in
+    place and no layer's pool is sliced out and stacked back (which
+    moved the whole pool through HBM three times a step and held a
+    second copy of it). The other caches go through the scan's xs/ys a
+    layer at a time: per-slot (mamba) state and dense caches as they
+    are, any other page pool as a one-layer stack at index 0."""
+    pools = {n: c for n, c in cache.items() if _carried(c)}
+    rest = {n: c for n, c in cache.items() if n not in pools}
+
+    def body(carry, inp):
+        x, pools = carry
+        gp, gc, layer = inp
+        pools, new_gc = dict(pools), {}
         for i, spec in enumerate(cfg.pattern):
-            lp = gp[f"L{i}"]
+            name = f"L{i}"
+            lp = gp[name]
             h = rmsnorm(x, lp["ln"], cfg.norm_eps)
-            if spec.kind == "attn":
-                y, new_gc[f"L{i}"] = attn_step(spec, lp["attn"], h,
-                                               gc[f"L{i}"])
+            if spec.kind == "attn" and name in pools:
+                y, pools[name] = attn_step(spec, lp["attn"], h, pools[name],
+                                           layer)
+            elif spec.kind == "attn" and PAGE_LEAVES.issuperset(gc[name]):
+                one = {k: a[None] for k, a in gc[name].items()}
+                y, one = attn_step(spec, lp["attn"], h, one, 0)
+                new_gc[name] = {k: a[0] for k, a in one.items()}
+            elif spec.kind == "attn":
+                y, new_gc[name] = attn_step(spec, lp["attn"], h, gc[name],
+                                            layer)
             else:
-                y, new_gc[f"L{i}"] = mam.mamba_decode(
-                    cfg, lp["mamba"], h, gc[f"L{i}"])
+                y, new_gc[name] = mam.mamba_decode(cfg, lp["mamba"], h,
+                                                   gc[name])
             if cfg.post_block_norms:
                 y = rmsnorm(y, lp["post_ln"], cfg.norm_eps)
             x = x + y
@@ -372,12 +408,13 @@ def _decode_scan(cfg, params, cache, x, attn_step):
                 if cfg.post_block_norms:
                     y = rmsnorm(y, lp["post_ln2"], cfg.norm_eps)
                 x = x + y
-        return x, new_gc
+        return (x, pools), new_gc
 
-    x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache),
-                                unroll=cfg.scan_unroll)
+    (x, pools), rest = jax.lax.scan(
+        body, (x, pools), (params["blocks"], rest, jnp.arange(cfg.n_groups)),
+        unroll=cfg.scan_unroll)
     x = rmsnorm(x, params["final_ln"], cfg.norm_eps)
-    return unembed(cfg, params, x), new_cache
+    return unembed(cfg, params, x), {**rest, **pools}
 
 
 def decode_step(cfg, params, cache, tokens, pos):
@@ -386,11 +423,11 @@ def decode_step(cfg, params, cache, tokens, pos):
     updated; callers should donate them."""
     x = embed_inputs(cfg, params, tokens)
     if cfg.mla is not None:
-        step = lambda spec, p, h, c: mla_mod.mla_decode(cfg, spec, p, h,
-                                                        c, pos)
+        step = lambda spec, p, h, c, layer: mla_mod.mla_decode(
+            cfg, spec, p, h, c, pos)
     else:
-        step = lambda spec, p, h, c: attn.attn_decode(cfg, spec, p, h,
-                                                      c, pos)
+        step = lambda spec, p, h, c, layer: attn.attn_decode(
+            cfg, spec, p, h, c, pos)
     logits, new_cache = _decode_scan(cfg, params, cache, x, step)
     return logits[:, 0], new_cache
 
@@ -401,11 +438,11 @@ def decode_step_paged(cfg, params, cache, tokens, pos, block_tables):
     Same contract as decode_step otherwise."""
     x = embed_inputs(cfg, params, tokens)
     if cfg.mla is not None:
-        step = lambda spec, p, h, c: mla_mod.mla_decode_paged(
-            cfg, spec, p, h, c, block_tables, pos)
+        step = lambda spec, p, h, c, layer: mla_mod.mla_decode_paged(
+            cfg, spec, p, h, c, block_tables, pos, layer)
     else:
-        step = lambda spec, p, h, c: attn.attn_decode_paged(
-            cfg, spec, p, h, c, block_tables, pos)
+        step = lambda spec, p, h, c, layer: attn.attn_decode_paged(
+            cfg, spec, p, h, c, block_tables, pos, layer)
     logits, new_cache = _decode_scan(cfg, params, cache, x, step)
     return logits[:, 0], new_cache
 
@@ -461,11 +498,11 @@ def _extend_scan(cfg, params, cache, tokens, start_pos, block_tables,
     chunk_mask = jnp.arange(C)[None, :] < n_valid[:, None]
     x = embed_inputs(cfg, params, tokens)
     if cfg.mla is not None:
-        step = lambda spec, p, h, c: mla_mod.mla_extend_paged(
-            cfg, spec, p, h, c, block_tables, start_pos, chunk_mask)
+        step = lambda spec, p, h, c, layer: mla_mod.mla_extend_paged(
+            cfg, spec, p, h, c, block_tables, start_pos, chunk_mask, layer)
     else:
-        step = lambda spec, p, h, c: attn.attn_extend_paged(
-            cfg, spec, p, h, c, block_tables, start_pos, chunk_mask)
+        step = lambda spec, p, h, c, layer: attn.attn_extend_paged(
+            cfg, spec, p, h, c, block_tables, start_pos, chunk_mask, layer)
     return _decode_scan(cfg, params, cache, x, step)
 
 

@@ -112,9 +112,10 @@ def test_kv_bytes_per_token_head():
 # fused-dequant kernel vs oracle
 # ---------------------------------------------------------------------------
 
-def _quant_pool(rng, P, page, Hkv, hd, bits):
-    k = jnp.asarray(rng.standard_normal((P, page, Hkv, hd)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((P, page, Hkv, hd)), jnp.float32)
+def _quant_pool(rng, P, page, Hkv, hd, bits, L=1):
+    """Coded K/V pool leaves, stacked over L layers."""
+    k = jnp.asarray(rng.standard_normal((L, P, page, Hkv, hd)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((L, P, page, Hkv, hd)), jnp.float32)
     # iters=1 keeps the sweep fast; kernel parity is about consuming the
     # codes, not about how well they were fitted
     return (kv_pool_rows(*kv_quantize(k, bits, iters=1))
@@ -137,8 +138,8 @@ def test_quant_kernel_matches_oracle_sweep(page, bits):
     pool = _quant_pool(rng, P, page, Hkv, hd, bits)
     bt = jnp.asarray(rng.integers(1, P, (B, T)).astype(np.int32))
     ctx = jnp.asarray(ctx, jnp.int32)
-    want = ref.paged_attention_quant_ref(q, *pool, bt, ctx)
-    got = paged_attention_quant(q, *pool, bt, ctx, interpret=True)
+    want = ref.paged_attention_quant_ref(q, *pool, bt, ctx, 0)
+    got = paged_attention_quant(q, *pool, bt, ctx, 0, interpret=True)
     assert float(jnp.abs(got - want).max()) < 1e-5
 
 
@@ -151,11 +152,35 @@ def test_quant_kernel_matches_oracle_window_cap(window, cap):
     pool = _quant_pool(rng, P, page, Hkv, hd, 4)
     bt = jnp.asarray(rng.integers(1, P, (B, T)).astype(np.int32))
     ctx = jnp.asarray([1, 17, T * page], jnp.int32)
-    want = ref.paged_attention_quant_ref(q, *pool, bt, ctx,
+    want = ref.paged_attention_quant_ref(q, *pool, bt, ctx, 0,
                                          window=window, cap=cap)
-    got = paged_attention_quant(q, *pool, bt, ctx, window=window, cap=cap,
-                                interpret=True)
+    got = paged_attention_quant(q, *pool, bt, ctx, 0, window=window,
+                                cap=cap, interpret=True)
     assert float(jnp.abs(got - want).max()) < 1e-5
+
+
+@pytest.mark.parametrize("layer", [0, 1, 3])
+def test_quant_kernel_reads_its_layer_of_the_stack(layer):
+    """On a stacked coded pool whose layers differ, the kernel at
+    `layer` matches the oracle at `layer`, which is the one-layer oracle
+    on that layer's codes and scales, and differs from every other
+    layer's."""
+    rng = np.random.default_rng(5)
+    L, B, Hkv, rep, hd, P, page, T = 4, 3, 2, 2, 64, 6, 8, 3
+    q = jnp.asarray(rng.standard_normal((B, Hkv, rep, hd)), jnp.float32)
+    pool = _quant_pool(rng, P, page, Hkv, hd, 4, L=L)
+    bt = jnp.asarray(rng.integers(1, P, (B, T)).astype(np.int32))
+    ctx = jnp.asarray([2, 13, T * page], jnp.int32)
+    got = paged_attention_quant(q, *pool, bt, ctx, jnp.int32(layer),
+                                interpret=True)
+    want = ref.paged_attention_quant_ref(q, *pool, bt, ctx, layer)
+    alone = ref.paged_attention_quant_ref(
+        q, *(a[layer:layer + 1] for a in pool), bt, ctx, 0)
+    assert float(jnp.abs(got - want).max()) < 1e-5
+    assert float(jnp.abs(want - alone).max()) == 0.0
+    for other in set(range(L)) - {layer}:
+        wrong = ref.paged_attention_quant_ref(q, *pool, bt, ctx, other)
+        assert float(jnp.abs(got - wrong).max()) > 1e-2
 
 
 def test_quant_oracle_approaches_fp_oracle_with_bits():
@@ -163,16 +188,18 @@ def test_quant_oracle_approaches_fp_oracle_with_bits():
     rng = np.random.default_rng(3)
     B, Hkv, rep, hd, P, page, T = 3, 2, 2, 64, 6, 8, 3
     q = jnp.asarray(rng.standard_normal((B, Hkv, rep, hd)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((P, page, Hkv, hd)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((P, page, Hkv, hd)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((1, P, page, Hkv, hd)),
+                     jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((1, P, page, Hkv, hd)),
+                     jnp.float32)
     bt = jnp.asarray(rng.integers(1, P, (B, T)).astype(np.int32))
     ctx = jnp.asarray([1, 10, T * page], jnp.int32)
-    want = ref.paged_attention_ref(q, kp, vp, bt, ctx)
+    want = ref.paged_attention_ref(q, kp, vp, bt, ctx, 0)
     errs = []
     for bits in (2, 4, 8):
         pool = (kv_pool_rows(*kv_quantize(kp, bits))
                 + kv_pool_rows(*kv_quantize(vp, bits)))
-        got = ref.paged_attention_quant_ref(q, *pool, bt, ctx)
+        got = ref.paged_attention_quant_ref(q, *pool, bt, ctx, 0)
         errs.append(float(jnp.abs(got - want).max()))
     # random N(0,1) K/V is the adversarial case (softmax amplifies any
     # coding error), so gate the decay, not a small absolute bound

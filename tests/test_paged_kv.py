@@ -5,10 +5,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import get_config
+from repro.configs import get_config, smoke_config
 from repro.kernels import ref
 from repro.kernels.paged_attention import paged_attention
 from repro.models import init_params
+from repro.models.model import PAGE_LEAVES, init_paged_cache
 from repro.serve import OutOfPages, PagedKVCache, Request, ServeEngine
 
 KEY = jax.random.PRNGKey(0)
@@ -38,18 +39,23 @@ def _run(cfg, params, reqs, **kw):
 # kernel vs oracle
 # ---------------------------------------------------------------------------
 
+def _pages(rng, *shape):
+    return jnp.asarray(rng.standard_normal(shape).astype(np.float32))
+
+
 @pytest.mark.parametrize("window,cap", [(None, None), (10, None),
                                         (None, 30.0), (7, 50.0)])
 def test_paged_attention_kernel_matches_ref(window, cap):
     rng = np.random.default_rng(0)
     B, Hkv, rep, hd, P, page, T = 3, 2, 4, 64, 9, 16, 4
-    q = jnp.asarray(rng.standard_normal((B, Hkv, rep, hd)).astype(np.float32))
-    kp = jnp.asarray(rng.standard_normal((P, page, Hkv, hd)).astype(np.float32))
-    vp = jnp.asarray(rng.standard_normal((P, page, Hkv, hd)).astype(np.float32))
+    q = _pages(rng, B, Hkv, rep, hd)
+    kp = _pages(rng, 1, P, page, Hkv, hd)       # a one-layer stack
+    vp = _pages(rng, 1, P, page, Hkv, hd)
     bt = jnp.asarray(rng.integers(1, P, (B, T)).astype(np.int32))
     ctx = jnp.asarray([1, 17, T * page], jnp.int32)   # 1 token .. full
-    want = ref.paged_attention_ref(q, kp, vp, bt, ctx, window=window, cap=cap)
-    got = paged_attention(q, kp, vp, bt, ctx, window=window, cap=cap,
+    want = ref.paged_attention_ref(q, kp, vp, bt, ctx, 0, window=window,
+                                   cap=cap)
+    got = paged_attention(q, kp, vp, bt, ctx, 0, window=window, cap=cap,
                           interpret=True)
     assert float(jnp.abs(got - want).max()) < 1e-5
 
@@ -64,14 +70,38 @@ def test_paged_attention_kernel_parity_page_size_sweep(page):
     P = T + 3
     ctx = [1, page - 1, page, page + 1, 2 * page + 1, T * page]
     B = len(ctx)
-    q = jnp.asarray(rng.standard_normal((B, Hkv, rep, hd)).astype(np.float32))
-    kp = jnp.asarray(rng.standard_normal((P, page, Hkv, hd)).astype(np.float32))
-    vp = jnp.asarray(rng.standard_normal((P, page, Hkv, hd)).astype(np.float32))
+    q = _pages(rng, B, Hkv, rep, hd)
+    kp = _pages(rng, 1, P, page, Hkv, hd)
+    vp = _pages(rng, 1, P, page, Hkv, hd)
     bt = jnp.asarray(rng.integers(1, P, (B, T)).astype(np.int32))
     ctx = jnp.asarray(ctx, jnp.int32)
-    want = ref.paged_attention_ref(q, kp, vp, bt, ctx)
-    got = paged_attention(q, kp, vp, bt, ctx, interpret=True)
+    want = ref.paged_attention_ref(q, kp, vp, bt, ctx, 0)
+    got = paged_attention(q, kp, vp, bt, ctx, 0, interpret=True)
     assert float(jnp.abs(got - want).max()) < 1e-5
+
+
+@pytest.mark.parametrize("layer", [0, 2, 4])
+def test_paged_attention_kernel_reads_its_layer_of_the_stack(layer):
+    """On a stacked pool whose layers differ, the kernel at `layer`
+    matches the oracle at `layer`, and the oracle at `layer` is the
+    one-layer oracle on that layer's pool, not on any other."""
+    rng = np.random.default_rng(11)
+    L, B, Hkv, rep, hd, P, page, T = 5, 3, 2, 2, 64, 7, 8, 3
+    q = _pages(rng, B, Hkv, rep, hd)
+    kp = _pages(rng, L, P, page, Hkv, hd)
+    vp = _pages(rng, L, P, page, Hkv, hd)
+    bt = jnp.asarray(rng.integers(1, P, (B, T)).astype(np.int32))
+    ctx = jnp.asarray([3, 12, T * page], jnp.int32)
+    got = paged_attention(q, kp, vp, bt, ctx, jnp.int32(layer),
+                          interpret=True)
+    want = ref.paged_attention_ref(q, kp, vp, bt, ctx, layer)
+    alone = ref.paged_attention_ref(q, kp[layer:layer + 1],
+                                    vp[layer:layer + 1], bt, ctx, 0)
+    assert float(jnp.abs(got - want).max()) < 1e-5
+    assert float(jnp.abs(want - alone).max()) == 0.0
+    for other in set(range(L)) - {layer}:
+        wrong = ref.paged_attention_ref(q, kp, vp, bt, ctx, other)
+        assert float(jnp.abs(got - wrong).max()) > 1e-2
 
 
 # ---------------------------------------------------------------------------
@@ -322,3 +352,128 @@ def test_max_pages_per_seq_zero_raises():
     with pytest.raises(ValueError, match="max_pages_per_seq"):
         PagedKVCache(None, n_pages=8, page_size=4, max_seqs=2,
                      max_pages_per_seq=0, create_pool=False)
+
+
+# ---------------------------------------------------------------------------
+# the page pool through the layer scan
+# ---------------------------------------------------------------------------
+
+def _lane_cfg(n_layers=3):
+    """tiny-lm at head_dim 128: its raw pages fill whole TPU lane tiles,
+    so the decode scan carries the stacked pool and addresses pages by
+    (layer, page)."""
+    return _tiny_cfg().replace(n_layers=n_layers, head_dim=128)
+
+
+def _scan_of(fn, *args, length):
+    """The layer scan of fn's jaxpr: (carry avals, xs avals)."""
+    def eqns(jaxpr):
+        for e in jaxpr.eqns:
+            yield e
+            for v in e.params.values():
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        yield from eqns(inner)
+
+    scans = [e for e in eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+             if e.primitive.name == "scan" and e.params["length"] == length]
+    e = max(scans, key=lambda e: len(e.invars))
+    c, n = e.params["num_consts"], e.params["num_carry"]
+    avals = [v.aval for v in e.invars]
+    return avals[c:c + n], avals[c + n:]
+
+
+def _shapes(avals):
+    return {tuple(a.shape) for a in avals}
+
+
+def _cache_shapes(cache, pages):
+    return {tuple(a.shape) for path, a
+            in jax.tree_util.tree_leaves_with_path(cache)
+            if (path[-1].key in PAGE_LEAVES) == pages}
+
+
+@pytest.mark.parametrize("arch,head_dim,kv_bits,carried", [
+    ("tiny-lm", 128, 0, True),
+    ("jamba-1.5-large-398b", 128, 0, True),
+    ("tiny-lm", 64, 0, False),
+    ("tiny-lm", 128, 4, False),
+])
+def test_decode_scan_carries_lane_dense_page_pools(arch, head_dim, kv_bits,
+                                                   carried):
+    """decode_step_paged: a page pool whose rows fill whole lane tiles
+    sits in the layer scan's carry, not in its xs; per-slot mamba state
+    (the hybrid) sits in xs. Pools with narrower rows (head_dim 64,
+    binary-coded scale rows) stay in xs, a layer at a time."""
+    from repro.models.model import decode_step_paged
+    base = get_config(arch) if arch == "tiny-lm" else smoke_config(arch)
+    cfg = base.replace(dtype="float32", remat="none", head_dim=head_dim,
+                       n_layers=2 * len(base.pattern))
+    params = jax.eval_shape(lambda: init_params(cfg, KEY))
+    B, T, page, n_pages = 2, 3, 16, 7
+    cache = jax.eval_shape(lambda: init_paged_cache(
+        cfg, n_pages, page, B, kv_bits=kv_bits))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    carry, xs = _scan_of(
+        lambda p, c, t, s, bt: decode_step_paged(cfg, p, c, t, s, bt),
+        params, cache, i32(B, 1), i32(B), i32(B, T), length=cfg.n_groups)
+    pages = _cache_shapes(cache, True)
+    assert pages
+    if carried:
+        assert pages <= _shapes(carry)
+        assert not pages & _shapes(xs)
+    else:
+        assert pages <= _shapes(xs)
+        assert not pages & _shapes(carry)
+    per_slot = _cache_shapes(cache, False)
+    assert per_slot <= _shapes(xs)
+    assert not per_slot & _shapes(carry)
+    if arch != "tiny-lm":
+        assert per_slot                     # the hybrid's mamba state
+
+
+def test_extend_scan_carries_the_page_pool():
+    """extend_paged (chunked prefill, the prefix-hit admission) runs the
+    same scan: the stacked pool rides its carry."""
+    from repro.models.model import extend_paged
+    cfg = _lane_cfg()
+    params = jax.eval_shape(lambda: init_params(cfg, KEY))
+    cache = jax.eval_shape(lambda: init_paged_cache(cfg, 7, 16, 2))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    carry, xs = _scan_of(
+        lambda p, c, t, s, bt, n: extend_paged(cfg, p, c, t, s, bt, n),
+        params, cache, i32(1, 8), i32(1), i32(1, 3), i32(1),
+        length=cfg.n_groups)
+    pages = _cache_shapes(cache, True)
+    assert pages <= _shapes(carry) and not pages & _shapes(xs)
+
+
+def test_carried_pool_engine_matches_dense_with_sharing_and_forks():
+    """A three-layer engine whose stacked pool rides the scan carry:
+    prefix hits admit through extend_paged, a partial shared page is
+    forked copy-on-write, and every output is token-identical to the
+    dense engine. Every layer writes and reads its own (layer, page)
+    slots, so a layer index off by any amount changes the tokens."""
+    cfg = _lane_cfg()
+    p = init_params(cfg, KEY)
+    prefix = (np.arange(40, dtype=np.int32) * 3 + 5) % cfg.vocab_size
+    mk = lambda: [[Request(prompt=np.concatenate(
+        [prefix, (np.arange(4 + i, dtype=np.int32) * 7 + i) % 199]),
+        max_new_tokens=6)] for i in range(3)]
+
+    def serve(**kw):
+        eng = ServeEngine(cfg, p, batch_size=2, max_len=96,
+                          dtype="float32", **kw)
+        rounds = mk()
+        eng.run(rounds[0])          # seeds the prefix index
+        eng.run(rounds[1] + rounds[2])
+        return [r.out for rs in rounds for r in rs], eng
+
+    want, _ = serve()
+    got, eng = serve(cache_kind="paged", page_size=16)
+    from repro.models.model import _carried
+    assert all(_carried(c) for c in eng.cache.values())
+    assert eng.stats["prefix_hits"] >= 2
+    assert eng.stats["cow_forks"] >= 1
+    assert got == want

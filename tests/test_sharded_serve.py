@@ -288,7 +288,18 @@ def test_sharded_engine_matches_single_device():
     """Greedy decode over a 2-way data mesh is token-identical to the
     single-device paged engine — mixed prompt lengths, growth across
     page boundaries, more requests than slots."""
-    cfg = _tiny_cfg()
+    _sharded_matches_single_device(_tiny_cfg())
+
+
+@needs2
+def test_sharded_carried_pool_matches_single_device():
+    """The same at head_dim 128, where the decode scan carries the
+    stacked, page-sharded pool and each layer addresses (layer, page)."""
+    _sharded_matches_single_device(_tiny_cfg().replace(head_dim=128,
+                                                       n_layers=3))
+
+
+def _sharded_matches_single_device(cfg):
     p = init_params(cfg, KEY)
     mk = lambda: [Request(prompt=(np.arange(10 + i % 3) * 7 + i)
                           .astype(np.int32) % cfg.vocab_size,

@@ -25,6 +25,7 @@ BITS = 3
 KN = [(1024, 3072), (3072, 1024)]          # qwen3-0.6b wg/wu and wd
 # paged decode at qwen3-0.6b attention widths
 B, HKV, REP, HD, PAGE, N_PAGES, T, KV_BITS = 4, 8, 2, 128, 64, 64, 8, 4
+LAYERS = 28
 
 
 @pytest.fixture(scope="module")
@@ -89,22 +90,24 @@ def test_bcq_expert_matmul_compiles(one_chip, group_size, K, N):
 
 
 def test_paged_attention_compiles(one_chip):
-    pages = _sds(one_chip, (N_PAGES, PAGE, HKV, HD), jnp.bfloat16)
+    pages = _sds(one_chip, (LAYERS, N_PAGES, PAGE, HKV, HD), jnp.bfloat16)
     _compile(paged_attention, _sds(one_chip, (B, HKV, REP, HD), jnp.bfloat16),
              pages, pages, _sds(one_chip, (B, T), jnp.int32),
-             _sds(one_chip, (B,), jnp.int32))
+             _sds(one_chip, (B,), jnp.int32), _sds(one_chip, (), jnp.int32))
 
 
 @pytest.mark.parametrize("kv_group_size", [0, 64])
 def test_paged_attention_quant_compiles(one_chip, kv_group_size):
     G = HD // kv_group_size if kv_group_size else 1
-    rows = [_sds(one_chip, (N_PAGES, PAGE, width), dt) for width, dt in (
+    rows = [_sds(one_chip, (LAYERS, N_PAGES, PAGE, width), dt)
+            for width, dt in (
         (HKV * KV_BITS * HD // 32, jnp.uint32),
         (HKV * G * KV_BITS, jnp.float32),
         (HKV * G, jnp.float32))]
     _compile(paged_attention_quant,
              _sds(one_chip, (B, HKV, REP, HD), jnp.bfloat16), *rows, *rows,
-             _sds(one_chip, (B, T), jnp.int32), _sds(one_chip, (B,), jnp.int32))
+             _sds(one_chip, (B, T), jnp.int32), _sds(one_chip, (B,), jnp.int32),
+             _sds(one_chip, (), jnp.int32))
 
 
 def _decode_step_text(topo, monkeypatch, mesh_shape, kv_bits):
@@ -171,3 +174,82 @@ def test_served_decode_step_compiles_tensor_parallel(topo, monkeypatch,
                              text)
     widths = {int(n) for _, n in kernel_outs}
     assert 1536 in widths and 3072 not in widths, sorted(widths)
+
+
+# the served page pools at their real sizes: (arch, pages, slots, pages
+# a row); an extend admission of one 128-token chunk
+SERVED = [("qwen3-0.6b", 512, 32, 32), ("qwen3-4b", 640, 64, 16)]
+EXTEND_TOKENS = 128
+POOL_OP = re.compile(r"= \w+\[([\d,]*)\]\S* "
+                     r"(copy|dynamic-slice|dynamic-update-slice)\(")
+
+
+def _pool_ops(text, pool_shapes):
+    """(computation, op, shape) of every copy, dynamic-slice and
+    dynamic-update-slice in optimized HLO whose result has one of
+    pool_shapes."""
+    found, comp = [], None
+    for line in text.splitlines():
+        if line.startswith(("%", "ENTRY")):
+            comp = "ENTRY" if line.startswith("ENTRY") else "nested"
+        m = POOL_OP.search(line)
+        if m:
+            shape = tuple(int(d) for d in m.group(1).split(",") if d)
+            if shape in pool_shapes:
+                found.append((comp, m.group(2), shape))
+    return found
+
+
+@pytest.mark.parametrize("kind", ["decode_paged", "extend_paged"])
+@pytest.mark.parametrize("kv_bits", [0, KV_BITS])
+@pytest.mark.parametrize("arch,n_pages,slots,row_pages", SERVED)
+def test_served_steps_update_the_pool_in_place(one_chip, monkeypatch, arch,
+                                               n_pages, slots, row_pages,
+                                               kv_bits, kind):
+    """The decode and extend programs at real pool sizes. Raw bf16
+    pages at head_dim 128 ride the layer scan's carry: no copy, slice
+    or update has a layer's pool shape or the stacked pool's, and the
+    program needs under 1 % of the pool in temporaries (a pool moved by
+    value through the scan needed a whole second pool). The coded pool
+    of kv_bits 4 has scale rows narrower than a lane tile and goes a
+    layer at a time: its stack is never copied inside the scan."""
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models import init_params
+    from repro.models.model import init_paged_cache
+    from repro.quant import QuantSpec
+    from repro.quant.abstract import quantize_params_abstract
+    from repro.serve import compile_cache
+
+    monkeypatch.setattr(ops, "FORCE_PALLAS", True)
+    monkeypatch.setattr(ops, "interpret", lambda: False)
+    cfg = get_config(arch)
+    spec = QuantSpec.from_config(cfg.quant, mode="packed", bits=BITS,
+                                 group_size=128)
+    on = lambda tree: jax.tree.map(
+        lambda x: _sds(one_chip, x.shape, x.dtype), tree)
+    params = on(quantize_params_abstract(cfg, jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))), spec=spec))
+    cache = jax.eval_shape(lambda: init_paged_cache(
+        cfg, n_pages, PAGE, slots, kv_bits=kv_bits))
+    i32 = lambda *shape: _sds(one_chip, shape, jnp.int32)
+    step = compile_cache.get(kind, cfg)
+    if kind == "decode_paged":
+        args = (i32(slots, 1), i32(slots), i32(slots, row_pages),
+                i32(slots), i32(slots))
+    else:
+        args = (i32(1, EXTEND_TOKENS), i32(1), i32(1, row_pages), i32(1))
+    compiled = step.lower(params, on(cache), *args).compile()
+    leaves = jax.tree.leaves(cache)
+    stacks = {tuple(x.shape) for x in leaves}
+    layers = {tuple(x.shape[1:]) for x in leaves} | {
+        (1,) + tuple(x.shape[1:]) for x in leaves}
+    found = _pool_ops(compiled.as_text(), stacks | layers)
+    if kv_bits:
+        assert not [f for f in found
+                    if f[0] != "ENTRY" and f[1] == "copy" and f[2] in stacks]
+        return
+    assert not found, sorted(set(found))
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in leaves)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 0.01 * pool_bytes, (temp, pool_bytes)
