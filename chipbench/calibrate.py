@@ -91,7 +91,8 @@ def gaps(cell_name, seeds, n_control, n_witness, seconds, dump_dir):
     limits = check.limits(cell_name)
     for i, seed in enumerate(seeds):
         t0 = time.time()
-        eng, loop, traffic = R.build(config, mix, seed)
+        eng, loop, traffic = R.build(config, mix, seed,
+                                     chips=cell["chips"])
         win = drive(loop, traffic, seconds)
         e2e = R.end_to_end(win, mix)
         picked = check.sample(win.tracks, mix["check_requests"], seed)
@@ -109,7 +110,8 @@ def gaps(cell_name, seeds, n_control, n_witness, seconds, dump_dir):
                "unanswered": unanswered,
                "longest_out": max(len(t.req.out) for t in picked),
                "ttft_p95_ms": e2e["ttft_p95_ms"],
-               "itl_p95_ms": e2e["itl_p95_ms"], "tok_s": e2e["tok_s"],
+               "itl_p95_ms": e2e["itl_p95_ms"],
+               "itl_mean_ms": e2e["itl_mean_ms"], "tok_s": e2e["tok_s"],
                "wall_s": time.time() - t0}
         out["top"], out["off_share"] = where(scores, picked)
         if control:
@@ -142,7 +144,7 @@ def knee(cell_name, rates, seconds, seed):
     cell = spec.cell(cell_name, bench)
     config = spec.load_config(cell["config"], bench)
     mix = spec.load_traffic(cell["traffic"])
-    eng, loop, warm = R.build(config, mix, seed)
+    eng, loop, warm = R.build(config, mix, seed, chips=cell["chips"])
     for i, rate in enumerate(rates):
         m = dict(mix, rate=rate)
         t = Traffic(m, warm.vocab, seed + 1 + i)
@@ -159,6 +161,7 @@ def knee(cell_name, rates, seconds, seed):
             "cell": cell_name, "rate": rate, "due": len(due),
             "ttft_p50_ms": float(np.median(ttft)) if ttft else None,
             "ttft_p95_ms": e["ttft_p95_ms"], "itl_p95_ms": e["itl_p95_ms"],
+            "itl_mean_ms": e["itl_mean_ms"],
             "queue_wait_p50_ms": float(np.median(waits)) if waits else None,
             "backlog_at_close": backlog, "tok_s": e["tok_s"]}), flush=True)
 
@@ -178,8 +181,12 @@ def main(argv=None):
     import os
     os.environ["JAX_COMPILATION_CACHE_DIR"] = str(R.CACHE_DIR)
     import jax
-    if jax.devices()[0].platform != "tpu":
-        print("calibrate: no TPU; nothing was run", file=sys.stderr)
+    from chipbench.lib import spec
+    devs = jax.devices()
+    if devs[0].platform != "tpu" or \
+            len(devs) < spec.cell(args.workload)["chips"]:
+        print("calibrate: not enough TPU chips; nothing was run",
+              file=sys.stderr)
         return 2
     R.enable_cache()
     seeds = [int(s) for s in args.seeds.split(",")]

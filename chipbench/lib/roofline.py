@@ -44,13 +44,28 @@ def nbytes(dt: str, dims: tuple) -> float:
     return n * ITEMSIZE[dt]
 
 
+FLOATS = ("bf16", "f16", "f32")
+
+
 def codes_index(shapes) -> int | None:
     """Index of the packed code planes, u32 (bits, K/32, N), among a
     call's shapes (result first), where a float operand comes before
     them; None if there are none."""
     for j in range(2, len(shapes)):
         if shapes[j][0] == "u32" and len(shapes[j][1]) == 3 \
-                and shapes[j - 1][0] in ("bf16", "f16", "f32"):
+                and shapes[j - 1][0] in FLOATS:
+            return j
+    return None
+
+
+def expert_codes_index(shapes) -> int | None:
+    """Index of a batched-expert GEMM's code planes, u32
+    (E, bits, K/32, N), among a call's shapes, where the operand before
+    them is a float x (E, M, K); None if there are none."""
+    for j in range(2, len(shapes)):
+        (cdt, c), (xdt, x) = shapes[j], shapes[j - 1]
+        if cdt == "u32" and len(c) == 4 and xdt in FLOATS \
+                and len(x) == 3 and x[0] == c[0] and x[2] == 32 * c[2]:
             return j
     return None
 
@@ -71,6 +86,22 @@ def bcq_cost(shapes) -> tuple[float, float, dict]:
     return flops, b, {"M": M, "K": K, "N": N, "bits": codes[0]}
 
 
+def bcq_expert_cost(shapes, routed_rows: int) -> tuple[float, float, dict]:
+    """A batched-expert BCQ GEMM call (kernels/bcq_matmul.py:
+    bcq_expert_matmul): x (E, M, K), codes u32 (E, bits, K/32, N), then
+    alphas and betas. x holds each expert's M rows of capacity, most of
+    them padding; only the `routed_rows` (token, expert) pairs routed to
+    the call's experts are work. Every operand is read once and the
+    result written once."""
+    j = expert_codes_index(shapes)
+    (E, M, K), codes = shapes[j - 1][1], shapes[j][1]
+    N = codes[3]
+    flops = 2.0 * routed_rows * K * N
+    b = sum(nbytes(*s) for s in shapes)
+    return flops, b, {"E": E, "M": M, "K": K, "N": N, "bits": codes[1],
+                      "rows": routed_rows}
+
+
 def paged_attn_cost(live_rows: int, sum_ctx: int, heads: int,
                     kv_heads: int, hd: int, kv_itemsize: float,
                     act_itemsize: float = 2) -> tuple[float, float]:
@@ -83,10 +114,15 @@ def paged_attn_cost(live_rows: int, sum_ctx: int, heads: int,
 
 
 def linear_params(dm: dict) -> int:
-    """Weights every token passes through, per layer times layers."""
+    """Weights every token passes through, per layer times layers: the
+    attention projections, and the gated MLP -- with experts, the
+    router and the MLPs of the top-k experts a token is routed to."""
     d, f, hd = dm["d"], dm["f"], dm["hd"]
     attn = d * (dm["heads"] + 2 * dm["kv_heads"]) * hd + dm["heads"] * hd * d
-    return dm["layers"] * (attn + 3 * d * f)
+    mlp = 3 * d * f
+    if dm.get("experts"):
+        mlp = dm["top_k"] * mlp + d * dm["experts"]
+    return dm["layers"] * (attn + mlp)
 
 
 def model_flops(dm: dict, admissions, ticks) -> float:

@@ -3,12 +3,25 @@
 Everything that belongs to one configuration, one traffic mix or one
 per-layer metric is a file of its own, found by name:
 
-    chipbench/configs/<config>.json        sizes, as run
-    chipbench/configs/<reference>.py       its plain float32 reference
-    chipbench/traffic/<traffic>.json       parameters of the generator
-    chipbench/metrics/<metric>.py          reader: read(run) -> value|None
+    chipbench/configs/<config>.json     sizes, as run, naming its
+                                        reference; a serve.mesh
+                                        {"data", "model"} for cells on
+                                        several chips
+    chipbench/configs/<reference>.py    its plain float32 reference:
+        score(config, seed, rows, control=) -> per-position logits;
+        _layer, _head, ROWS_PER_BLOCK, LEN_UNIT (compiled by
+        tests/test_compile_v5e.py)
+    chipbench/programs/<model_type>.py  the program side of it:
+        model_config(config) -> the program's ModelConfig, checked
+        against the file;
+        params(seed, config, mesh) -> the served parameters, made on
+        the device: placed on `mesh`, or on one device when it is None
+    chipbench/traffic/<traffic>.json    parameters of the generator
+    chipbench/cells/<cell>.json         the cell's limits of `correct`
+    chipbench/metrics/<metric>.py       reader: read(run) -> value|None
 
-so a later cell, mix or metric is added by adding files and entries.
+so a later cell, mix, metric or model is added by adding files and
+entries.
 """
 from __future__ import annotations
 
@@ -61,6 +74,14 @@ def reference_module(config: dict):
     ref = config["reference"]
     return _load_module(BENCH_DIR / "configs" / f"{ref}.py",
                         f"chipbench_ref_{ref}")
+
+
+def program_module(config: dict):
+    """The program side of a configuration, by its published
+    model_type."""
+    prog = config["model_type"]
+    return _load_module(BENCH_DIR / "programs" / f"{prog}.py",
+                        f"chipbench_program_{prog}")
 
 
 def metric_reader(name: str):

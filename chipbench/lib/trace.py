@@ -4,21 +4,24 @@ device busy time, idle gaps by host phase, and per-kernel device time.
 Idle time is split over the host phases that were open during it (the
 adapter's phases do not overlap); what no phase covers is "none".
 
-Device work is read from the "XLA Ops" line of each TPU plane; the host
-phases are the TraceAnnotation spans the serving-loop adapter writes
-(serve_loop.PHASES) and the "trace_window" span around the traced
-iterations, which sets the window. Busy time is the union of op
+Device work is read from the "XLA Ops" line of the TPU planes of the
+devices the run used: a host may write a plane for each of its chips,
+and busy time and the breakdown are averaged over the used ones only.
+The host phases are the TraceAnnotation spans the serving-loop adapter
+writes (serve_loop.PHASES) and the "trace_window" span around the
+traced iterations, which sets the window. Busy time is the union of op
 intervals inside the window, so overlapping ops count once.
 
 Kernels are told apart by the HLO of the custom call: a Pallas call
-whose operands include packed uint32 code planes is a BCQ GEMM; one
-whose operands are an int32 block table, context lengths and pages is
-paged attention. On a TPU v5e the instruction text is the event's name
-("%bcq_matmul.68 = bf16[64,9728]{...} custom-call(...),
-custom_call_target=..."); its stats (TEXT_STATS) are read where the
-name is short. Only the result and the operands count: the text is cut
-at `custom_call_target`, after which the layout constraints repeat the
-operand types.
+whose operands include packed uint32 code planes is a BCQ GEMM -- the
+batched-expert one (`bcq_expert`) where x is (E, M, K) and the codes
+(E, bits, K/32, N); one whose operands are an int32 block table,
+context lengths and pages is paged attention. On a TPU v5e the
+instruction text is the event's name ("%bcq_matmul.68 =
+bf16[64,9728]{...} custom-call(...), custom_call_target=..."); its
+stats (TEXT_STATS) are read where the name is short. Only the result
+and the operands count: the text is cut at `custom_call_target`, after
+which the layout constraints repeat the operand types.
 
 A `while` or `conditional` op spans the ops of its body on the same
 line: it counts towards busy time (a union), not in the breakdown of
@@ -31,10 +34,13 @@ import os
 import re
 from collections import defaultdict
 
-from chipbench.lib.roofline import codes_index, parse_shapes
+from chipbench.lib.roofline import (codes_index, expert_codes_index,
+                                    parse_shapes)
 
 WINDOW = "trace_window"
 TEXT_STATS = ("long_name", "hlo_text", "tf_op", "name")
+KERNELS = ("bcq", "bcq_expert", "paged_attention")
+TPU_PLANE = re.compile(r"/device:TPU:(\d+)")
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -109,12 +115,15 @@ def op_text(ev) -> str:
 
 
 def kernel_of(text: str) -> str | None:
-    """'bcq' or 'paged_attention' for the Pallas calls of this program,
-    from the operand types of the custom call; None otherwise."""
+    """'bcq', 'bcq_expert' or 'paged_attention' for the Pallas calls of
+    this program, from the operand types of the custom call; None
+    otherwise."""
     if "tpu_custom_call" not in text and "custom-call" not in text:
         return None
     sh = parse_shapes(text)
     ops = sh[1:]
+    if expert_codes_index(sh) is not None:
+        return "bcq_expert"
     if codes_index(sh) is not None:
         return "bcq"
     if len(ops) >= 5 and ops[0][0] == "s32" and len(ops[0][1]) == 2 \
@@ -129,23 +138,36 @@ def _short(name: str) -> str:
     return re.sub(r"[.\d]+$", "", name) or name
 
 
-def reduce(path: str) -> dict:
-    """Window, busy time, kernels and breakdown of one trace file."""
-    return reduce_events(load(path))
+def reduce(path: str, devices=None) -> dict:
+    """Window, busy time, kernels and breakdown of one trace file, over
+    the planes of `devices` (ids; every TPU plane when None)."""
+    return reduce_events(load(path), devices)
 
 
-def reduce_events(tr: dict) -> dict:
+def used_planes(tr: dict, devices=None) -> dict:
+    """The device planes of `tr` whose TPU id is among `devices`."""
+    if devices is None:
+        return tr["device"]
+    ids = {str(d) for d in devices}
+    return {name: evs for name, evs in tr["device"].items()
+            if (m := TPU_PLANE.match(name)) and m.group(1) in ids}
+
+
+def reduce_events(tr: dict, devices=None) -> dict:
     """Window, busy time, kernels and breakdown of loaded events
-    (`load`'s form)."""
+    (`load`'s form), over the planes of `devices` (ids; all when
+    None)."""
     wins = [h for h in tr["host"] if h[0] == WINDOW]
-    if not wins or not tr["device"]:
-        raise ValueError("trace has no trace_window span or no TPU plane")
+    planes = used_planes(tr, devices)
+    if not wins or not planes:
+        raise ValueError(f"trace has no trace_window span or no plane of "
+                         f"TPU {devices}: {sorted(tr['device'])}")
     lo, hi = wins[0][1], wins[0][2]
     phases = [h for h in tr["host"] if h[0] != WINDOW
               and h[2] > lo and h[1] < hi]
     busy_all, calls, ops_time = [], [], defaultdict(float)
     gap_by_phase = defaultdict(float)
-    for plane, evs in tr["device"].items():
+    for evs in planes.values():
         inside = [e for e in evs if lo <= e[1] and e[2] <= hi]
         busy, gaps = union([(e[1], e[2]) for e in inside], lo, hi)
         busy_all.append(busy)
@@ -173,7 +195,7 @@ def reduce_events(tr: dict) -> dict:
         "busy_s": sum(busy_all) / n / 1e9,
         "calls": calls,
         "kernel_s": {k: v / 1e9 for k, v in ops_time.items()
-                     if k in ("bcq", "paged_attention")},
+                     if k in KERNELS},
         "breakdown": {"device_ops": [[k, v / 1e9 / n] for k, v in top],
                       "idle_gaps": [[k, v / 1e9 / n] for k, v in idle]},
         "span_ns": (lo, hi),

@@ -1,16 +1,16 @@
-"""Seeded random weights, and the program's served form of them.
+"""Seeded random weights, drawn alike for the program and the reference.
 
 `layer_weights` and `embedding` draw a model's bf16 weights from the
-seed, one layer at a time. Both sides use them: the program's packed
-parameters (`program_params`) and the plain reference
+seed, one layer at a time. Both sides use them: the program's served
+parameters (chipbench/programs/<program>.py) and the plain reference
 (configs/<reference>.py), which codes and runs them on its own.
 
 The served form is what GPTQT emits (quant/qlinear.py:QuantizedTensor:
 w`bits` sign planes packed 32 per uint32 along K, float32 alphas
-(G, N, bits) and betas (G, N) per group of `group_size` rows). The
-codes come from the repo's calibration-free greedy residual coding
-(core/binary_coding.py:bcq_greedy) in one jitted call; GPTQT's
-calibrated solve is offline work and is not run here.
+(G, N, bits) and betas (G, N) per group of `group_size` rows). `_code`
+makes it with the repo's calibration-free greedy residual coding
+(core/binary_coding.py:bcq_greedy), inside the program's jitted maker;
+GPTQT's calibrated solve is offline work and is not run here.
 """
 from __future__ import annotations
 
@@ -26,14 +26,21 @@ EMBED_STD = 0.02
 
 
 def dims(config: dict) -> dict:
-    """The sizes a run needs, from a configuration file's keys."""
+    """The sizes a run needs, from a configuration file's keys. A file
+    without `head_dim` has hidden_size / heads; one without experts has
+    0 experts and 0 of them per token (`f` is then the dense MLP's
+    width, else each expert's)."""
     c = config
     return {"d": c["hidden_size"], "f": c["intermediate_size"],
             "layers": c["num_hidden_layers"],
             "heads": c["num_attention_heads"],
-            "kv_heads": c["num_key_value_heads"], "hd": c["head_dim"],
+            "kv_heads": c["num_key_value_heads"],
+            "hd": c.get("head_dim")
+            or c["hidden_size"] // c["num_attention_heads"],
             "vocab": c["vocab_size"], "eps": c["rms_norm_eps"],
-            "theta": float(c["rope_theta"])}
+            "theta": float(c["rope_theta"]),
+            "experts": c.get("num_local_experts", 0),
+            "top_k": c.get("num_experts_per_tok", 0)}
 
 
 def root_key(seed: int):
@@ -44,11 +51,16 @@ def root_key(seed: int):
 
 
 def shapes(dm: dict) -> dict:
+    """One layer's weights. With experts the MLP's matrices are stacks
+    (experts, K, N) and a router (d, experts) joins them."""
     d, f, hd = dm["d"], dm["f"], dm["hd"]
     qd, kd = dm["heads"] * hd, dm["kv_heads"] * hd
+    mlp = {"wg": (d, f), "wu": (d, f), "wd": (f, d)}
+    if dm.get("experts"):
+        E = dm["experts"]
+        mlp = {n: (E, *s) for n, s in mlp.items()} | {"router": (d, E)}
     return {"wq": (d, qd), "wk": (d, kd), "wv": (d, kd), "wo": (qd, d),
-            "wg": (d, f), "wu": (d, f), "wd": (f, d),
-            "ln": (d,), "ln2": (d,), "qn": (hd,), "kn": (hd,)}
+            **mlp, "ln": (d,), "ln2": (d,), "qn": (hd,), "kn": (hd,)}
 
 
 def layer_weights(key, i, dm: dict) -> dict:
@@ -58,7 +70,7 @@ def layer_weights(key, i, dm: dict) -> dict:
     out = {}
     for j, (name, shp) in enumerate(sorted(shapes(dm).items())):
         kj = jax.random.fold_in(k, j)
-        std = NORM_STD if len(shp) == 1 else shp[0] ** -0.5
+        std = NORM_STD if len(shp) == 1 else shp[-2] ** -0.5
         out[name] = (jax.random.normal(kj, shp, jnp.float32)
                      * std).astype(jnp.bfloat16)
     return out
@@ -92,19 +104,3 @@ def _code(w, bits: int, group: int):
         betas=jnp.zeros((G, N), jnp.float32), k_in=K,
         orig_dtype="bfloat16")
 
-
-def program_params(seed: int, dm: dict, bits: int, group: int):
-    """The served parameter tree of a dense qk-norm GQA model, made on
-    the device in one jitted call. The layers are drawn and coded one
-    at a time (lax.map), so the bf16 model never exists whole."""
-    def build(key):
-        def one(i):
-            w = layer_weights(key, i, dm)
-            return {"ln": w["ln"], "ln2": w["ln2"],
-                    "attn": {**{n: _code(w[n], bits, group) for n in ATTN},
-                             "qn": w["qn"], "kn": w["kn"]},
-                    "mlp": {n: _code(w[n], bits, group) for n in MLP}}
-        blocks = jax.lax.map(one, jnp.arange(dm["layers"]))
-        e, fl = embedding(key, dm)
-        return {"embed": e, "final_ln": fl, "blocks": {"L0": blocks}}
-    return jax.jit(build)(root_key(seed))

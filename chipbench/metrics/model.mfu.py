@@ -1,6 +1,7 @@
 """Whole-step model FLOP utilization: the useful model FLOPs of the
 prompt and output tokens processed in the traced span (roofline.py:
-model_flops) over the span's length and the chip's bf16 peak, in %."""
+model_flops) over the span's length and the bf16 peak of the cell's
+chips, in %."""
 from chipbench.lib.roofline import model_flops
 
 
@@ -13,4 +14,5 @@ def read(run):
     if not adm and not ticks:
         return None
     f = model_flops(run.dm, adm, ticks)
-    return 100.0 * f / run.trace["window_s"] / run.peaks["bf16_flops_per_s"]
+    peak = run.peaks["bf16_flops_per_s"] * run.chips
+    return 100.0 * f / run.trace["window_s"] / peak

@@ -1,4 +1,4 @@
-"""Gap between decode steps in the chat cell, moving itl_p95_ms: over
+"""Gap between decode steps in the chat cell, moving itl_mean_ms: over
 consecutive decode ticks of the untraced part of the window, the p95 of
 the start of the next `serve.decode.dispatch` less the end of this
 `serve.decode.wait`, in ms: how long the device had no decode step

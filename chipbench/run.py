@@ -4,16 +4,20 @@
     python chipbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell (BENCHMARK.json `workloads`) names a configuration and a
-traffic mix, found by name under chipbench/. One process does all of
-it: it makes the weights from the seed on the device and codes them
-into the served w3 format, builds the paged ServeEngine, compiles
-every shape the mix reaches, offers the traffic for its ramp, then
-measures for --seconds. After the window it reads the device's peak
-memory, frees the program's state and scores a sample of the finished
-requests with the plain float32 reference. The last line of stdout is
-one JSON object; with --trace 0 its metrics are the cell's end-to-end
-metrics, with --trace 1 its per-layer metrics, read from a profiler
-trace of the first TRACE_S seconds of the window and from the
+traffic mix, found by name under chipbench/; the configuration's
+model_type names its program side (chipbench/programs/). One process
+does all of it: it makes the weights from the seed on the device,
+coded into the served w3 format and placed on the cell's chips (a mesh
+over the first `chips` devices, as the configuration's serve.mesh
+states, when there are several), builds the paged ServeEngine on them,
+compiles every shape the mix reaches, offers the traffic for its ramp,
+then measures for --seconds. After the window it reads the peak
+memory of the fullest of the cell's devices, frees the program's state
+and scores a sample of the finished requests with the plain float32
+reference. The last line of stdout is one JSON object; with --trace 0
+its metrics are the cell's end-to-end metrics, with --trace 1 its
+per-layer metrics, read from a profiler trace of the first TRACE_S
+seconds of the window (the planes of the cell's devices) and from the
 program's own counters.
 
 CHIPBENCH_KEEP_TRACE=<dir> keeps a copy of the traced run's profile
@@ -59,23 +63,6 @@ def info(msg: str) -> None:
     print(f"[chipbench] {msg}", file=sys.stderr, flush=True)
 
 
-def program_config(config: dict):
-    """The program's model config for a configuration file, checked key
-    by key against the file so the file is what runs."""
-    from repro.configs import get_config
-    from chipbench.lib.weights import dims
-    cfg = get_config(config["arch"])
-    dm = dims(config)
-    got = {"d": cfg.d_model, "f": cfg.d_ff, "layers": cfg.n_layers,
-           "heads": cfg.n_heads, "kv_heads": cfg.n_kv_heads,
-           "hd": cfg.resolved_head_dim, "vocab": cfg.vocab_size,
-           "eps": cfg.norm_eps, "theta": float(cfg.rope_theta)}
-    if got != dm or not cfg.qk_norm or not cfg.tie_embeddings:
-        raise ValueError(f"program config {cfg.name} {got} differs from "
-                         f"the configuration file {dm}")
-    return cfg.replace(dtype=config["serve"]["dtype"])
-
-
 class Tracer:
     def __init__(self, path: Path):
         self.path = path
@@ -106,25 +93,47 @@ def end_to_end(win, mix) -> dict:
         n_tok += sum(1 for x in ts if W0 <= x <= W1)
         itl += [(b - a) * 1e3 for a, b in zip(ts, ts[1:]) if W0 <= b <= W1]
     return {"ttft_p95_ms": pct(ttft, 95), "itl_p95_ms": pct(itl, 95),
+            "itl_mean_ms": sum(itl) / len(itl) if itl else None,
             "tok_s": n_tok / (W1 - W0),
             "n_due": len(due), "n_ttft": len(ttft), "n_itl": len(itl)}
 
 
-def build(config: dict, mix: dict, seed: int, *, trace: bool = False,
-          engine_hook=None):
-    """Weights from the seed, the engine, and every shape the mix
-    reaches compiled; returns (engine, loop, traffic)."""
+def serve_mesh(config: dict, chips: int):
+    """The mesh of a cell on `chips` devices, over the first of them,
+    (data, model) as the configuration's serve.mesh states; None for
+    one chip."""
+    if chips == 1:
+        return None
+    from repro.launch.mesh import make_serve_mesh
+    m = config["serve"].get("mesh")
+    if m is None or m["data"] * m["model"] != chips:
+        raise ValueError(f"serve.mesh {m} is not {chips} chips")
+    return make_serve_mesh(data=m["data"], model=m["model"])
+
+
+def device_peak(dev) -> int:
+    """One device's peak bytes in use so far (0 where the backend does
+    not say)."""
+    return int((dev.memory_stats() or {}).get("peak_bytes_in_use", 0))
+
+
+def build(config: dict, mix: dict, seed: int, *, chips: int = 1,
+          trace: bool = False, engine_hook=None):
+    """Weights from the seed, the engine on the cell's chips, and every
+    shape the mix reaches compiled; returns (engine, loop, traffic)."""
     import jax
+    from chipbench.lib import spec
     from chipbench.lib.serve_loop import Loop, warm_up
     from chipbench.lib.traffic import Traffic
-    from chipbench.lib.weights import dims, program_params
+    from chipbench.lib.weights import dims
     from repro.serve import ServeEngine
     dm = dims(config)
-    cfg = program_config(config) if "arch" in config else config["_cfg"]
-    q, sv = config["quant"], config["serve"]
+    prog = spec.program_module(config)
+    cfg = prog.model_config(config)
+    sv = config["serve"]
+    mesh = serve_mesh(config, chips)
     t = time.time()
-    params = jax.block_until_ready(
-        program_params(seed, dm, q["bits"], q["group_size"]))
+    params = jax.block_until_ready(prog.params(seed, config, mesh))
     info(f"weights made and coded on the device in {time.time() - t:.1f} s")
     eng = ServeEngine(cfg, params, batch_size=mix["slots"],
                       max_len=mix["max_len"], cache_kind="paged",
@@ -132,7 +141,7 @@ def build(config: dict, mix: dict, seed: int, *, trace: bool = False,
                       page_size=sv["page_size"], kv_bits=sv["kv_bits"],
                       prefix_sharing=sv["prefix_sharing"],
                       prefill_chunk=sv["prefill_chunk"],
-                      speculate=sv["speculate"])
+                      speculate=sv["speculate"], mesh=mesh)
     if engine_hook is not None:
         engine_hook(eng)
     loop = Loop(eng, annotate=trace)
@@ -194,10 +203,10 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
     limits = limits or check.limits(cell_name)
     enable_cache()
     devs = jax.devices()
-    dev = devs[0]
+    dev, used = devs[0], devs[:cell["chips"]]
     compiles = count_compiles()
-    eng, loop, traffic = build(config, mix, seed, trace=trace,
-                               engine_hook=engine_hook)
+    eng, loop, traffic = build(config, mix, seed, chips=cell["chips"],
+                               trace=trace, engine_hook=engine_hook)
     tracer = Tracer(WORK_DIR / "trace") if trace else None
     win = drive(loop, traffic, seconds, trace_s=TRACE_S, tracer=tracer)
     setup_s = win.t0 - T_PROCESS
@@ -205,8 +214,7 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
     in_window = [ev for ts, ev in compiles if win.t0 <= ts <= win.t1]
     info(f"window {win.t1 - win.t0:.2f} s: {e2e['n_due']} requests due, "
          f"{e2e['n_itl']} token gaps, {len(in_window)} compiles inside")
-    stats = dev.memory_stats() or {}
-    mem_peak = int(stats.get("peak_bytes_in_use", 0))
+    mem_peak = max(device_peak(d) for d in used)
 
     reduced = None
     if trace:
@@ -215,11 +223,13 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
             shutil.copytree(WORK_DIR / "trace",
                             os.environ["CHIPBENCH_KEEP_TRACE"],
                             dirs_exist_ok=True)
-        reduced = reduce(find_xplane(str(WORK_DIR / "trace")))
+        reduced = reduce(find_xplane(str(WORK_DIR / "trace")),
+                         devices=[d.id for d in used])
         shutil.rmtree(WORK_DIR / "trace", ignore_errors=True)
 
     run = SimpleNamespace(window=win, loop=loop, trace=reduced,
                           dm=dims(config), mix=mix, config=config,
+                          chips=len(used),
                           peaks=peak_table(dev.device_kind)
                           if dev.platform == "tpu" else None)
     per_layer = {}

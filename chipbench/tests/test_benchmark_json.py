@@ -44,6 +44,26 @@ def test_configs():
         assert c["name"] in {w["config"] for w in B["workloads"]}
 
 
+@pytest.mark.parametrize("name", [c["name"] for c in B["configs"]])
+def test_config_has_a_program_and_a_mesh_for_its_chips(name):
+    """The program side of the file's model_type exists and has both
+    functions the harness calls; a configuration that a cell runs on several
+    chips states its serve.mesh, and a serve.mesh is as many chips as
+    every cell of the configuration asks for."""
+    config = spec.load_config(name, B)
+    prog = spec.program_module(config)
+    assert callable(prog.model_config) and callable(prog.params)
+    mesh = config["serve"].get("mesh")
+    for w in B["workloads"]:
+        if w["config"] != name:
+            continue
+        if mesh is None:
+            assert w["chips"] == 1, w["name"]
+        else:
+            assert set(mesh) == {"data", "model"}
+            assert mesh["data"] * mesh["model"] == w["chips"], w["name"]
+
+
 def test_workloads_name_their_files():
     pairs = set()
     for w in B["workloads"]:

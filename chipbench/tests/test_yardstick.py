@@ -6,6 +6,7 @@ to run without a chip.
 """
 from __future__ import annotations
 
+import json
 import os
 import shutil
 import subprocess
@@ -36,6 +37,16 @@ PA_TEXT = ("%paged_attention.9 = bf16[64,8,4,128]{3,2,1,0} custom-call("
            "custom_call_target=\"tpu_custom_call\", operand_layout_constraints="
            "{s32[64,16]{1,0}, s32[64]{0}, bf16[64,8,4,128]{3,2,1,0}, "
            "bf16[640,64,8,128]{3,2,1,0}, bf16[640,64,8,128]{3,2,1,0}}")
+
+# bcq_expert_matmul as its Pallas call sees it: x (E, M, K), codes
+# (E, bits, K/32, N), alphas (E, G, bits, N), betas (E, G, 1, N)
+EXPERT_TEXT = ("%bcq_expert_matmul.3 = bf16[8,64,14336]{2,1,0} custom-call("
+               "bf16[8,64,4096]{2,1,0} %x, u32[8,3,128,14336]{3,2,1,0} %c, "
+               "f32[8,32,3,14336]{3,2,1,0} %a, f32[8,32,1,14336]{3,2,1,0} "
+               "%b), custom_call_target=\"tpu_custom_call\", "
+               "operand_layout_constraints={bf16[8,64,4096]{2,1,0}, "
+               "u32[8,3,128,14336]{3,2,1,0}, f32[8,32,3,14336]{3,2,1,0}, "
+               "f32[8,32,1,14336]{3,2,1,0}}")
 
 
 # ---------------------------------------------------------------- trace
@@ -84,11 +95,36 @@ def test_reduce_attributes_kernels_and_idle_gaps():
                    "fusion": pytest.approx(10e-9)}
 
 
+def test_reduce_reads_only_the_planes_of_the_used_devices():
+    """A 1-chip run on a 4-chip host: the profiler writes a plane for
+    every chip, and the three idle ones change nothing."""
+    one = trace.reduce_events(_events())
+    tr = _events()
+    for i in (1, 2, 3):
+        tr["device"][f"/device:TPU:{i}"] = []
+    four = trace.reduce_events(tr, devices=[0])
+    for k in ("window_s", "busy_s", "kernel_s", "breakdown"):
+        assert four[k] == one[k], k
+    # over all four planes the same events read a quarter of the busy
+    # time: what the device filter is for
+    assert trace.reduce_events(tr)["busy_s"] == pytest.approx(
+        one["busy_s"] / 4)
+    with pytest.raises(ValueError):
+        trace.reduce_events(tr, devices=[7])
+
+
 def test_kernel_of_tells_bcq_from_paged_attention():
     assert trace.kernel_of(BCQ_TEXT) == "bcq"
     assert trace.kernel_of(PA_TEXT) == "paged_attention"
+    assert trace.kernel_of(EXPERT_TEXT) == "bcq_expert"
     assert trace.kernel_of("%fusion.1 = bf16[8]{0} fusion(bf16[8] %x)") \
         is None
+    # 4-D u32 codes whose expert count or K does not match x's are no
+    # batched-expert GEMM
+    assert trace.kernel_of(EXPERT_TEXT.replace("bf16[8,64,4096]",
+                                               "bf16[4,64,4096]")) is None
+    assert trace.kernel_of(EXPERT_TEXT.replace("bf16[8,64,4096]",
+                                               "bf16[8,64,2048]")) is None
 
 
 # ------------------------------------------------------------- roofline
@@ -105,6 +141,22 @@ def test_bcq_cost_hand_worked():
     # out + x (bf16), codes (u32), alphas + betas (f32)
     assert b == (64 * 9728 * 2 + 64 * 2560 * 2 + 3 * 80 * 9728 * 4
                  + 20 * 3 * 9728 * 4 + 20 * 9728 * 4)
+
+
+def test_bcq_expert_cost_hand_worked():
+    text = trace.op_text((EXPERT_TEXT, 0, 0, {}))
+    sh = roofline.parse_shapes(text)
+    assert roofline.codes_index(sh) is None
+    # 8 experts with 64 rows of capacity each; 24 (token, expert) pairs
+    # routed here: the work is theirs, not the 512 padded rows'
+    f, b, d = roofline.bcq_expert_cost(sh, 24)
+    assert d == {"E": 8, "M": 64, "K": 4096, "N": 14336, "bits": 3,
+                 "rows": 24}
+    assert f == 2 * 24 * 4096 * 14336
+    # out + x (bf16), codes (u32), alphas + betas (f32), each once
+    assert b == (8 * 64 * 14336 * 2 + 8 * 64 * 4096 * 2
+                 + 8 * 3 * 128 * 14336 * 4 + 8 * 32 * 3 * 14336 * 4
+                 + 8 * 32 * 14336 * 4)
 
 
 def test_paged_attention_cost_hand_worked():
@@ -134,6 +186,50 @@ def test_model_flops_hand_worked():
     want = (3 * 2 * lin + 2 * 4 * 10 + att * (3 + 4 + 5)
             + 2 * (2 * lin + 2 * 4 * 10) + att * 5)
     assert got == want
+
+
+def _file_dims(name):
+    from chipbench.lib.weights import dims
+    return dims(json.loads((BENCH_DIR / "configs" / name).read_text()))
+
+
+def test_linear_params_counts_routed_experts_only():
+    """Mixtral-8x7B (its config.json: no head_dim, 8 local experts, 2 a
+    token, expert width 14336) through the router and two experts."""
+    from chipbench.lib.weights import dims
+    mixtral = dims({"hidden_size": 4096, "intermediate_size": 14336,
+                    "num_hidden_layers": 32, "num_attention_heads": 32,
+                    "num_key_value_heads": 8, "vocab_size": 32000,
+                    "rms_norm_eps": 1e-5, "rope_theta": 1e6,
+                    "num_local_experts": 8, "num_experts_per_tok": 2})
+    assert (mixtral["hd"], mixtral["experts"], mixtral["top_k"]) == \
+        (128, 8, 2)
+    assert roofline.linear_params(mixtral) == 12_617_515_008 == \
+        32 * (41_943_040 + 352_321_536 + 32_768)
+    # dense configurations count as before: Qwen3-4B's 36 layers of
+    # 41.9M attention and 74.7M MLP weights
+    q4 = _file_dims("qwen3-4b.w3g128.json")
+    assert (q4["experts"], q4["top_k"]) == (0, 0)
+    assert roofline.linear_params(q4) == 3_633_315_840 == \
+        36 * (2560 * 48 * 128 + 32 * 128 * 2560 + 3 * 2560 * 9728)
+
+
+def test_expert_layers_draw_a_stack_per_expert_and_a_router():
+    from chipbench.lib.weights import dims, layer_weights, root_key
+    c = {"hidden_size": 64, "intermediate_size": 256,
+         "num_hidden_layers": 1, "num_attention_heads": 4,
+         "num_key_value_heads": 2, "vocab_size": 128, "rms_norm_eps": 1e-5,
+         "rope_theta": 1e6, "num_local_experts": 8, "num_experts_per_tok": 2}
+    w = layer_weights(root_key(3), 0, dims(c))
+    assert w["wg"].shape == w["wu"].shape == (8, 64, 256)
+    assert w["wd"].shape == (8, 256, 64) and w["router"].shape == (64, 8)
+    # N(0, 1/K) along each expert's own K
+    for n, K in (("wg", 64), ("wd", 256)):
+        assert float(jnp.std(w[n].astype(jnp.float32))) == \
+            pytest.approx(K ** -0.5, rel=0.05)
+    dense = layer_weights(root_key(3), 0, dims(
+        {k: v for k, v in c.items() if "expert" not in k}))
+    assert dense["wg"].shape == (64, 256) and "router" not in dense
 
 
 # -------------------------------------------------------------- traffic
@@ -234,13 +330,13 @@ def test_reference_matches_the_program_forward_at_float32():
     program's coded weights agree: the served format holds what the
     reference codes, and both compute the same architecture."""
     from chipbench.configs import qwen3 as ref
-    from chipbench.lib.weights import dims, program_params
+    from chipbench.lib.spec import program_module
     from repro.models import forward
     from repro.quant import QuantizedTensor
     config = tiny.config()
-    cfg = config["_cfg"].replace(dtype="float32")
-    q = config["quant"]
-    params = program_params(5, dims(config), q["bits"], q["group_size"])
+    prog = program_module(config)
+    cfg = prog.model_config(config).replace(dtype="float32")
+    params = prog.params(5, config, None)
     is_qt = lambda x: isinstance(x, QuantizedTensor)
     p32 = jax.tree.map(lambda x: x.dequant(jnp.float32) if is_qt(x)
                        else x.astype(jnp.float32), params, is_leaf=is_qt)
@@ -256,6 +352,26 @@ def test_reference_matches_the_program_forward_at_float32():
 
 
 # ------------------------------------------------------------- refusal
+
+def test_end_to_end_hand_worked():
+    """Token gaps count where their later token is in the window; the
+    mean is over every such gap, the rate over tokens in the window."""
+    from types import SimpleNamespace as N
+    import chipbench.run as run
+    tracks = [N(due=1.0, t_first=1.5, t_tokens=[1.5, 1.6, 1.9]),
+              N(due=-1.0, t_first=-0.5, t_tokens=[-0.5, 0.2, 0.3]),
+              N(due=9.0, t_first=9.5, t_tokens=[9.5, 10.5])]
+    e = run.end_to_end(N(t0=0.0, t1=10.0, tracks=tracks), {})
+    gaps_ms = [100.0, 300.0, 700.0, 100.0]      # 10.5 falls after t1
+    assert e["n_itl"] == 4
+    assert e["itl_mean_ms"] == pytest.approx(sum(gaps_ms) / 4)
+    assert e["itl_p95_ms"] == pytest.approx(np.percentile(gaps_ms, 95))
+    assert e["tok_s"] == pytest.approx(6 / 10.0)
+    assert e["n_due"] == 2
+    assert e["ttft_p95_ms"] == pytest.approx(np.percentile([500, 500], 95))
+    assert run.end_to_end(N(t0=0.0, t1=1.0, tracks=[]), {})[
+        "itl_mean_ms"] is None
+
 
 def _run(cwd, env_extra=None):
     env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}

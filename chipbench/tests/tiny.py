@@ -5,17 +5,18 @@ import json
 from chipbench.lib.spec import BENCH_DIR
 
 
-def config():
+def config(heads=4, kv_heads=2):
     from repro.configs.base import LayerSpec, ModelConfig
     c = json.loads((BENCH_DIR / "configs" /
                     "qwen3-0.6b.w3g128.json").read_text())
     c.update(hidden_size=256, intermediate_size=512, num_hidden_layers=2,
-             num_attention_heads=4, num_key_value_heads=2, head_dim=64,
-             vocab_size=512)
+             num_attention_heads=heads, num_key_value_heads=kv_heads,
+             head_dim=64, vocab_size=512)
     del c["arch"]
     c["_cfg"] = ModelConfig(
         name="tiny-qwen3", family="dense", n_layers=2, d_model=256,
-        n_heads=4, n_kv_heads=2, head_dim=64, d_ff=512, vocab_size=512,
+        n_heads=heads, n_kv_heads=kv_heads, head_dim=64, d_ff=512,
+        vocab_size=512,
         pattern=(LayerSpec(kind="attn", mlp="dense"),), qk_norm=True,
         rope_theta=1e6, tie_embeddings=True)
     return c
